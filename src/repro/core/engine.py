@@ -86,6 +86,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import frontier_words as fwords
 from repro.core.partition import PartitionedGraph
 from repro.core.problems import Problem
@@ -193,6 +194,9 @@ class EngineResult:
     labels: Dict[str, np.ndarray]  # unpadded, original vertex ids
     iterations: int
     converged: bool
+    # pull-kernel tiles run, summed over iterations and phases (None: no
+    # pull kernel ran, or the engine does not count them)
+    pull_tiles_run: int | None = None
 
 
 def prepare_labels(problem: Problem, g, pg: PartitionedGraph) -> Dict[str, jnp.ndarray]:
@@ -201,21 +205,25 @@ def prepare_labels(problem: Problem, g, pg: PartitionedGraph) -> Dict[str, jnp.n
     Lane-batched label fields arrive as (padded, L) — K vector lanes or
     packed reach words — and become (p, Vl, L): the permutation moves rows,
     the lane axis rides along untouched."""
-    padded = pg.padded_vertices
-    labels = problem.init_labels(g, padded)
-    out = {}
-    for k, v in labels.items():
-        v = np.asarray(v)
-        if v.ndim in (1, 2) and v.shape[0] == padded:
-            if pg.perm is not None:
-                # perm is a bijection on [0, V): every slot < V is re-assigned,
-                # slots >= V keep their natural padding init values.
-                moved = v.copy()
-                moved[pg.perm[: pg.num_vertices]] = v[: pg.num_vertices]
-                v = moved
-            v = v.reshape(pg.p, pg.vertices_per_core, *v.shape[1:])
-        out[k] = jnp.asarray(v)
-    return out
+    with telemetry.span("engine.prepare_labels"):
+        padded = pg.padded_vertices
+        with telemetry.span("init"):
+            labels = problem.init_labels(g, padded)
+        out = {}
+        for k, v in labels.items():
+            with telemetry.span("permute", field=k):
+                v = np.asarray(v)
+                if v.ndim in (1, 2) and v.shape[0] == padded:
+                    if pg.perm is not None:
+                        # perm is a bijection on [0, V): every slot < V is
+                        # re-assigned, slots >= V keep their padding init.
+                        moved = v.copy()
+                        moved[pg.perm[: pg.num_vertices]] = v[: pg.num_vertices]
+                        v = moved
+                    v = v.reshape(pg.p, pg.vertices_per_core, *v.shape[1:])
+            with telemetry.span("upload", field=k):
+                out[k] = jnp.asarray(v)
+        return out
 
 
 def unpad_labels(
@@ -306,6 +314,13 @@ def phase_consts_at(consts, m):
     }
 
 
+def _fetch_map(active):
+    if active is None:
+        return None
+    with jax.named_scope("coverage"):
+        return fwords.active_fetch_map(active)
+
+
 def channel_phase_reduce_pallas(problem, pg, gathered, cm, opts, active=None):
     """THE fused gather-map-reduce primitive (steps 1+2 of a phase), channel
     local: ONE ``pallas_call`` over grid (n, R, T) does unpack + gather + map
@@ -336,7 +351,7 @@ def channel_phase_reduce_pallas(problem, pg, gathered, cm, opts, active=None):
         cm["counts"],
         cm["word_hi"],
         cm["w"],
-        fwords.active_fetch_map(active) if active is not None else None,
+        _fetch_map(active),
         num_rows=pg.packed_rows_per_core,
         vb=pg.tile_vb,
         src_bits=pg.src_bits,
@@ -379,7 +394,7 @@ def channel_phase_scatter_pallas(problem, pg, gathered, cm, opts, active=None):
         cm["counts"],
         cm["word_hi"],
         cm["w"],
-        fwords.active_fetch_map(active) if active is not None else None,
+        _fetch_map(active),
         num_rows=pg.vertices_per_core,
         src_bits=pg.push_src_bits,
         kind=problem.reduce_kind,
@@ -410,9 +425,12 @@ def _gather_local(problem, pg, labels, m):
     """Single-process crossbar: every core's phase-m sub-interval is a local
     slice of the (p, Vl[, L]) payload — concatenating them IS the gathered
     block ((G,) laneless, (G, L) with a multi-query lane axis)."""
-    payload = problem.src_transform(labels)  # (p, Vl[, L]) elementwise
-    sub = jax.lax.dynamic_slice_in_dim(payload, m * pg.sub_size, pg.sub_size, axis=1)
-    return sub.reshape(pg.gathered_size, *payload.shape[2:])  # (G[, L])
+    with jax.named_scope("gather"):
+        payload = problem.src_transform(labels)  # (p, Vl[, L]) elementwise
+        sub = jax.lax.dynamic_slice_in_dim(
+            payload, m * pg.sub_size, pg.sub_size, axis=1
+        )
+        return sub.reshape(pg.gathered_size, *payload.shape[2:])  # (G[, L])
 
 
 def _phase_reduce_pallas(problem, pg, consts, labels, m, opts, active=None):
@@ -634,9 +652,10 @@ def make_iteration(
     def _words_of(old, new):
         # lane-batched labels carry a trailing lane axis: the frontier is the
         # UNION over lanes (a tile streams iff any live query needs it).
-        return fwords.frontier_words_from_labels(
-            old, new, pg.l, pg.sub_size, lanes=problem.lanes > 0
-        )
+        with jax.named_scope("frontier"):
+            return fwords.frontier_words_from_labels(
+                old, new, pg.l, pg.sub_size, lanes=problem.lanes > 0
+            )
 
     def _stats(active_tiles, use_dense, use_push=None, pop=None):
         out = {
@@ -661,7 +680,8 @@ def make_iteration(
 
         def _merge(labels, reduced):
             lab = labels[problem.merge_field]
-            merged = jnp.minimum(lab, reduced.astype(lab.dtype))
+            with jax.named_scope("merge"):
+                merged = jnp.minimum(lab, reduced.astype(lab.dtype))
             new = dict(labels)
             new[problem.merge_field] = merged
             return new, lab, merged
@@ -686,10 +706,11 @@ def make_iteration(
 
             def body(m, carry):
                 labels, nf, n_act = carry
-                active = active_fn_m(m, fw_in | nf)
+                with jax.named_scope("coverage"):
+                    active = active_fn_m(m, fw_in | nf)
+                    n_act = n_act + jnp.sum(active, dtype=jnp.int32)
                 new, lab, merged = _merge(labels, reduce_fn_m(m, labels, active))
                 nf = nf | _words_of(lab, merged)
-                n_act = n_act + jnp.sum(active, dtype=jnp.int32)
                 return new, nf, n_act
 
             def phase(m, carry):
@@ -797,16 +818,18 @@ def make_iteration(
                 if dynamic:
                     # synchronous phases only see LAST iteration's labels, so
                     # the input frontier alone is the live frontier.
-                    active = active_fn_m(m, frontier)
-                    n_act = n_act + jnp.sum(active, dtype=jnp.int32)
+                    with jax.named_scope("coverage"):
+                        active = active_fn_m(m, frontier)
+                        n_act = n_act + jnp.sum(active, dtype=jnp.int32)
                     reduced = reduce_fn_m(m, labels, active)
                 else:
                     reduced = reduce_fn_m(m, labels)
-                if problem.reduce_kind == "min":
-                    return jnp.minimum(acc, reduced.astype(acc.dtype)), n_act
-                if problem.reduce_kind == "or":
-                    return acc | reduced.astype(acc.dtype), n_act
-                return acc + reduced.astype(acc.dtype), n_act
+                with jax.named_scope("merge"):
+                    if problem.reduce_kind == "min":
+                        return jnp.minimum(acc, reduced.astype(acc.dtype)), n_act
+                    if problem.reduce_kind == "or":
+                        return acc | reduced.astype(acc.dtype), n_act
+                    return acc + reduced.astype(acc.dtype), n_act
 
             def phase(m, carry):
                 # push arm phase-level skip (see _phase_loop): a phase with
@@ -855,15 +878,13 @@ def make_iteration(
                 extras += (_stats(n_act, use_dense, use_push, pop if push_aware else None),)
             return (new, nf) + extras if extras else (new, nf)
 
-        if problem.reduce_kind == "min":
-            new = dict(labels)
-            merged = jnp.minimum(lab, acc.astype(lab.dtype))
-            new[problem.merge_field] = merged
-            if dynamic:
-                return _ret(new, _words_of(lab, merged))
-            return new
-        new = problem.finalize(labels, acc)
-        if dynamic:  # 'or' problems: monotone, so frontier scheduling applies
+        with jax.named_scope("merge"):
+            if problem.reduce_kind == "min":
+                new = dict(labels)
+                new[problem.merge_field] = jnp.minimum(lab, acc.astype(lab.dtype))
+            else:
+                new = problem.finalize(labels, acc)
+        if dynamic:  # min and 'or' problems: monotone, so the frontier applies
             return _ret(new, _words_of(lab, new[problem.merge_field]))
         return new
 
@@ -876,7 +897,9 @@ _make_iteration = make_iteration
 
 @partial(jax.jit, static_argnames=("problem", "pg", "opts"))
 def _run_jit(problem, pg, opts, labels, consts):
-    iteration = _make_iteration(problem, pg, opts, consts=consts)
+    """Labels, iterations, whether still changing, and the pull kernel's
+    tiles run on the dynamic schedule (None on the static one)."""
+    iteration = _make_iteration(problem, pg, opts, consts=consts, with_stats=True)
     if dynamic_skip_enabled(problem, pg, opts):
         # frontier-carried loop: the per-iteration label-change words both
         # schedule the next iteration's tiles AND are the convergence check
@@ -889,33 +912,39 @@ def _run_jit(problem, pg, opts, labels, consts):
             # the full frontier always takes the pull branch under 'auto').
 
             def cond(carry):
-                _, _, it, changed, _ = carry
+                _, _, it, changed, _, _ = carry
                 return jnp.logical_and(changed, it < opts.max_iters)
 
             def body(carry):
-                labels, fw, it, _, dirp = carry
-                new, nf, dirn = iteration(labels, fw, dirp)
-                return new, nf, it + 1, jnp.any(nf != jnp.uint32(0)), dirn
+                labels, fw, it, _, dirp, tiles = carry
+                new, nf, dirn, stats = iteration(labels, fw, dirp)
+                # a push iteration runs the push kernel, no pull tile
+                pulled = jnp.where(dirn, jnp.int32(0), stats["active_tiles"])
+                return (new, nf, it + 1, jnp.any(nf != jnp.uint32(0)), dirn,
+                        tiles + pulled)
 
-            labels, _, iters, changed, _ = jax.lax.while_loop(
+            labels, _, iters, changed, _, tiles = jax.lax.while_loop(
                 cond, body,
-                (labels, fw0, jnp.int32(0), jnp.bool_(True), jnp.bool_(False)),
+                (labels, fw0, jnp.int32(0), jnp.bool_(True), jnp.bool_(False),
+                 jnp.int32(0)),
             )
-            return labels, iters, changed
+            return labels, iters, changed, tiles
 
         def cond(carry):
-            _, _, it, changed = carry
+            _, _, it, changed, _ = carry
             return jnp.logical_and(changed, it < opts.max_iters)
 
         def body(carry):
-            labels, fw, it, _ = carry
-            new, nf = iteration(labels, fw)
-            return new, nf, it + 1, jnp.any(nf != jnp.uint32(0))
+            labels, fw, it, _, tiles = carry
+            new, nf, stats = iteration(labels, fw)
+            return (new, nf, it + 1, jnp.any(nf != jnp.uint32(0)),
+                    tiles + stats["active_tiles"])
 
-        labels, _, iters, changed = jax.lax.while_loop(
-            cond, body, (labels, fw0, jnp.int32(0), jnp.bool_(True))
+        labels, _, iters, changed, tiles = jax.lax.while_loop(
+            cond, body,
+            (labels, fw0, jnp.int32(0), jnp.bool_(True), jnp.int32(0)),
         )
-        return labels, iters, changed
+        return labels, iters, changed, tiles
 
     def cond(carry):
         _, it, changed = carry
@@ -930,7 +959,7 @@ def _run_jit(problem, pg, opts, labels, consts):
     labels, iters, changed = jax.lax.while_loop(
         cond, body, (labels, jnp.int32(0), jnp.bool_(True))
     )
-    return labels, iters, changed
+    return labels, iters, changed, None
 
 
 # LRU-bounded (was unbounded: a serving loop running many graphs pinned every
@@ -981,23 +1010,45 @@ def run(
     multi-query problem's traced computation depends only on its lane count,
     never on the root VALUES (those live in the label init), so admission
     batches reuse ONE template problem as the jit cache key and feed each
-    batch's roots through ``labels`` without retracing (launch/serve.py)."""
-    if labels is None:
-        labels = prepare_labels(problem, g, pg)
-    # opts is a frozen dataclass of primitives: hashable BY VALUE, so fresh
-    # EngineOptions() instances hit the jit cache (id-wrapping it caused a
-    # recompile per call — caught because benchmarks timed compiles).
-    pgw = _wrap(pg)
-    # the packed stream enters as jit ARGUMENTS, each host array uploaded
-    # once per partition and kept with its wrapper: baked in as constants, a
-    # graph500-scale stream would be serialized into (and slow) every
-    # executable.
-    consts = _edge_constants(problem, pg, opts, put=pgw.device_array)
-    labels, iters, changed = _run_jit(_wrap(problem), pgw, opts, labels, consts)
+    batch's roots through ``labels`` without retracing (launch/serve.py).
+
+    Spans (``repro.telemetry``): ``engine.run`` around the whole call;
+    ``engine.execute`` from the dispatch until the iteration count is back
+    on the host; ``engine.readback`` for the labels' copy back and
+    un-permutation. Counters: ``engine.pull_tiles_run``, the pull kernel's
+    tiles run over all iterations and phases, and ``engine.pull_tiles_real``,
+    the real tiles times iterations (Pallas backend only)."""
+    with telemetry.span("engine.run"):
+        if labels is None:
+            labels = prepare_labels(problem, g, pg)
+        # opts is a frozen dataclass of primitives: hashable BY VALUE, so
+        # fresh EngineOptions() instances hit the jit cache (id-wrapping it
+        # caused a recompile per call — caught because benchmarks timed
+        # compiles).
+        pgw = _wrap(pg)
+        # the packed stream enters as jit ARGUMENTS, each host array uploaded
+        # once per partition and kept with its wrapper: baked in as
+        # constants, a graph500-scale stream would be serialized into (and
+        # slow) every executable.
+        consts = _edge_constants(problem, pg, opts, put=pgw.device_array)
+        with telemetry.span("engine.execute"):
+            labels, iters, changed, tiles = _run_jit(
+                _wrap(problem), pgw, opts, labels, consts
+            )
+            iters, changed, tiles = jax.device_get((iters, changed, tiles))
+        iters = int(iters)
+        tiles_run = None
+        if opts.backend == "pallas":
+            real = iters * int(pg.tile_counts.sum())
+            # the static schedule runs every real tile each iteration
+            tiles_run = real if tiles is None else int(tiles)
+            telemetry.count("engine.pull_tiles_run", tiles_run)
+            telemetry.count("engine.pull_tiles_real", real)
+        with telemetry.span("engine.readback"):
+            out = unpad_labels(labels, pg)
     return EngineResult(
-        labels=unpad_labels(labels, pg),
-        iterations=int(iters),
-        converged=not bool(changed),
+        labels=out, iterations=iters, converged=not bool(changed),
+        pull_tiles_run=tiles_run,
     )
 
 
@@ -1009,7 +1060,8 @@ def run_frontier_trace(
     Same numerics as ``run`` (one jitted ``iteration(labels, frontier)`` per
     step), but stepped from the host so each iteration's active-tile count
     can be read back. Returns a dict with the final ``labels`` /
-    ``iterations`` / ``converged`` plus ``dynamic_skipped_tile_fraction`` — a
+    ``iterations`` / ``converged``, ``active_tiles`` (the tiles each
+    iteration's kernel ran), ``dynamic_skipped_tile_fraction`` — a
     per-iteration list over the SAME denominator as the static
     ``pg.skipped_tile_fraction`` (all (core, phase, row-block) x T_max tile
     slots; a push iteration's fraction uses the push stream's own (core,
@@ -1032,7 +1084,7 @@ def run_frontier_trace(
         pg.push_counts.size * pg.push_word.shape[3] if push_on else 0
     )
     prev = jnp.bool_(False)
-    fractions, directions = [], []
+    active, fractions, directions = [], [], []
     dense_iters, it, converged = 0, 0, False
     while it < opts.max_iters:
         if push_on:
@@ -1042,7 +1094,8 @@ def run_frontier_trace(
             labels, fw, stats = step(labels, fw)
             pushed = False
         total = total_push_tiles if pushed else total_tiles
-        fractions.append(1.0 - int(stats["active_tiles"]) / max(total, 1))
+        active.append(int(stats["active_tiles"]))
+        fractions.append(1.0 - active[-1] / max(total, 1))
         directions.append("push" if pushed else "pull")
         dense_iters += int(stats["use_dense"])
         it += 1
@@ -1053,6 +1106,7 @@ def run_frontier_trace(
         "labels": unpad_labels(labels, pg),
         "iterations": it,
         "converged": converged,
+        "active_tiles": active,
         "dynamic_skipped_tile_fraction": fractions,
         "mean_dynamic_skipped_tile_fraction": (
             float(np.mean(fractions)) if fractions else 0.0
@@ -1075,7 +1129,9 @@ class _Hashable:
         uploaded on first use."""
         hit = self._device.get(id(host))
         if hit is None:
-            hit = self._device[id(host)] = jnp.asarray(host)
+            # waits for the copy, so the span times the whole upload
+            with telemetry.span("engine.upload", bytes=int(host.nbytes)):
+                hit = self._device[id(host)] = jnp.asarray(host).block_until_ready()
         return hit
 
     def __getattr__(self, name):

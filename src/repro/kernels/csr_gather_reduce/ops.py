@@ -609,6 +609,7 @@ def split_map_from_row_orig(row_orig: np.ndarray, num_rows: int) -> np.ndarray:
     return out
 
 
+@jax.named_scope("split_fold")
 def combine_split_rows(
     reduced: jnp.ndarray,  # (..., P[, K]) level-1 kernel output, packed rows
     split_map: jnp.ndarray,  # (..., num_rows, S) packed positions, -1 = pad
