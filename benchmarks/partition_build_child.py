@@ -47,7 +47,7 @@ from repro.data.rmat import materialize, rmat_chunks
 _IDENTITY_FIELDS = (
     "src_gidx", "dst_lidx", "valid", "weights", "bucket_sizes",
     "tile_word", "tile_word_hi", "tile_counts", "tile_weights",
-    "tile_coverage", "tile_row_pos", "tile_row_orig", "tile_split_map",
+    "tile_coverage", "tile_fold_rows", "tile_fold_pos",
     "push_word", "push_word_hi", "push_counts", "push_weights",
     "push_coverage",
 )

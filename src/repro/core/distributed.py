@@ -11,8 +11,8 @@ local gathered block (the scratch pad).
 What each channel streams is the point (docs/distributed.md §3): the
 per-device edge constants are this core's shard of the partition-time packed
 stream — ``tile_word``/``tile_word_hi`` bit-packed index words, the
-scalar-prefetched ``tile_counts`` that skip padding tiles, and the hub-split
-``split_map`` — exactly the arrays ``PartitionedGraph.channel_arrays()``
+scalar-prefetched ``tile_counts`` that skip padding tiles, and the fold
+arrays ``fold_rows``/``fold_pos`` — exactly the arrays ``PartitionedGraph.channel_arrays()``
 hands the single-process engine. Phase processing is literally the same
 function (``engine.channel_phase_reduce_pallas``, the one Pallas phase-reduce
 implementation) invoked with a channel axis of 1, so every compression win
@@ -75,7 +75,7 @@ __all__ = [
 # traversal (docs/tile_layout.md §9) — dropped by channel_arrays for sum
 # problems, exactly like coverage.
 _CONST_KEYS = (
-    "word", "word_hi", "counts", "w", "row_pos", "split_map", "coverage",
+    "word", "word_hi", "counts", "w", "fold_rows", "fold_pos", "coverage",
     "push_word", "push_word_hi", "push_counts", "push_w", "push_coverage",
 )
 

@@ -35,10 +35,11 @@ Two step-2 backends, selected by ``EngineOptions.backend``:
     Consumes the partition-time (p, l, R, T, *tile) packed stream on
     ``PartitionedGraph``. The kernel follows the backend: compiled on a
     TPU, the Pallas interpreter on CPU (correctness-grade timings only).
-    Hub rows split at partition time reduce as independent
-    virtual rows in-kernel (level 1); a gather-based second-level combine
-    (``combine_split_rows``, the problem's reduce op + identity over the
-    ``tile_split_map``) folds the partials into true rows before apply.
+    Every natural row reduces in place (level 1); the few rows whose block
+    overflowed at partition time also reduce pieces in appended blocks, and
+    the second-level combine (``combine_split_rows``, the problem's reduce
+    op + identity over ``tile_fold_rows``/``tile_fold_pos``) folds those
+    pieces into their rows before apply — it gathers N x S partials, never V.
   * ``'xla'`` — the correctness oracle: materializes the (p, E_pad)
     contributions array via take/where and segment-reduces it. Bit-identical
     to the Pallas path for min problems; for sum problems (PageRank) results
@@ -282,7 +283,7 @@ def _edge_constants(
     ``put`` moves one host array to the device (``run`` passes a cache)."""
     if opts.backend == "pallas":
         # channel_arrays(problem) is the single source of truth for the packed
-        # stream layout (word/word_hi/counts/w/row_pos/split_map with a
+        # stream layout (word/word_hi/counts/w/fold_rows/fold_pos with a
         # leading core == channel axis) AND the weight-streaming rule; the
         # distributed engine NamedSharding-places the same dict over the mesh.
         # Weightless edge_op streams NO weight array at all: the kernel adds
@@ -333,9 +334,10 @@ def channel_phase_reduce_pallas(problem, pg, gathered, cm, opts, active=None):
     ``gathered`` is the (G,) crossbar block (locally sliced in-process;
     ``crossbar_exchange``-all-gathered across devices). ``cm`` is a phase
     slice of the packed constants (``phase_consts_at``). No (n, E_pad)
-    per-edge array is materialized. With hub-row splitting the kernel output
-    is over VIRTUAL rows and the second-level combine folds the partials into
-    natural rows (still no per-edge materialization). Returns (n, Vl).
+    per-edge array is materialized. The kernel output is over PACKED rows:
+    the natural blocks, read in place, and any appended overflow blocks,
+    whose pieces the second-level combine folds into their rows (still no
+    per-edge materialization). Returns (n, Vl).
 
     ``active`` (traced (n, R, T) bool, from ``frontier_active_tiles`` — must
     already be ANDed with the real-tile mask) engages frontier-aware dynamic
@@ -358,21 +360,13 @@ def channel_phase_reduce_pallas(problem, pg, gathered, cm, opts, active=None):
         kind=problem.reduce_kind,
         edge_op=problem.edge_op,
         identity=problem.identity,
-    )  # (n, R*vb) level-1 reductions in packed (virtual-)row space
-    if cm["split_map"] is not None:
-        # level-2 reduce (hub-row splitting): fold each natural row's
-        # virtual-row partials with the problem's reduce op; -1 padding
-        # contributes the problem's identity, never a stray 0.
-        reduced = combine_split_rows(
-            reduced, cm["split_map"], kind=problem.reduce_kind,
-            identity=problem.identity,
-        )
-    elif cm["row_pos"] is not None:  # undo degree-aware row packing
-        pos = cm["row_pos"]
-        if reduced.ndim == 3:  # trailing lane axis: size-1 index broadcasts
-            pos = pos[..., None]
-        reduced = jnp.take_along_axis(reduced, pos, axis=1)
-    return reduced
+    )  # (n, R*vb) level-1 reductions in packed-row space
+    # level 2: the natural blocks as a slice, the moved rows' pieces folded
+    # in with the problem's reduce op (padding reads its identity)
+    return combine_split_rows(
+        reduced, cm["fold_rows"], cm["fold_pos"], num_rows=pg.vertices_per_core,
+        kind=problem.reduce_kind, identity=problem.identity,
+    )
 
 
 def channel_phase_scatter_pallas(problem, pg, gathered, cm, opts, active=None):
@@ -897,8 +891,9 @@ _make_iteration = make_iteration
 
 @partial(jax.jit, static_argnames=("problem", "pg", "opts"))
 def _run_jit(problem, pg, opts, labels, consts):
-    """Labels, iterations, whether still changing, and the pull kernel's
-    tiles run on the dynamic schedule (None on the static one)."""
+    """Labels, iterations, whether still changing, the pull kernel's tiles
+    run on the dynamic schedule (None on the static one), and the pull
+    iterations where push may take some (None: every iteration pulls)."""
     iteration = _make_iteration(problem, pg, opts, consts=consts, with_stats=True)
     if dynamic_skip_enabled(problem, pg, opts):
         # frontier-carried loop: the per-iteration label-change words both
@@ -912,23 +907,23 @@ def _run_jit(problem, pg, opts, labels, consts):
             # the full frontier always takes the pull branch under 'auto').
 
             def cond(carry):
-                _, _, it, changed, _, _ = carry
+                _, _, it, changed, _, _, _ = carry
                 return jnp.logical_and(changed, it < opts.max_iters)
 
             def body(carry):
-                labels, fw, it, _, dirp, tiles = carry
+                labels, fw, it, _, dirp, tiles, pulls = carry
                 new, nf, dirn, stats = iteration(labels, fw, dirp)
                 # a push iteration runs the push kernel, no pull tile
                 pulled = jnp.where(dirn, jnp.int32(0), stats["active_tiles"])
                 return (new, nf, it + 1, jnp.any(nf != jnp.uint32(0)), dirn,
-                        tiles + pulled)
+                        tiles + pulled, pulls + jnp.where(dirn, 0, 1))
 
-            labels, _, iters, changed, _, tiles = jax.lax.while_loop(
+            labels, _, iters, changed, _, tiles, pulls = jax.lax.while_loop(
                 cond, body,
                 (labels, fw0, jnp.int32(0), jnp.bool_(True), jnp.bool_(False),
-                 jnp.int32(0)),
+                 jnp.int32(0), jnp.int32(0)),
             )
-            return labels, iters, changed, tiles
+            return labels, iters, changed, tiles, pulls
 
         def cond(carry):
             _, _, it, changed, _ = carry
@@ -944,7 +939,7 @@ def _run_jit(problem, pg, opts, labels, consts):
             cond, body,
             (labels, fw0, jnp.int32(0), jnp.bool_(True), jnp.int32(0)),
         )
-        return labels, iters, changed, tiles
+        return labels, iters, changed, tiles, None
 
     def cond(carry):
         _, it, changed = carry
@@ -959,7 +954,7 @@ def _run_jit(problem, pg, opts, labels, consts):
     labels, iters, changed = jax.lax.while_loop(
         cond, body, (labels, jnp.int32(0), jnp.bool_(True))
     )
-    return labels, iters, changed, None
+    return labels, iters, changed, None, None
 
 
 # LRU-bounded (was unbounded: a serving loop running many graphs pinned every
@@ -1015,9 +1010,11 @@ def run(
     Spans (``repro.telemetry``): ``engine.run`` around the whole call;
     ``engine.execute`` from the dispatch until the iteration count is back
     on the host; ``engine.readback`` for the labels' copy back and
-    un-permutation. Counters: ``engine.pull_tiles_run``, the pull kernel's
-    tiles run over all iterations and phases, and ``engine.pull_tiles_real``,
-    the real tiles times iterations (Pallas backend only)."""
+    un-permutation. Counters (Pallas backend only): ``engine.pull_tiles_run``,
+    the pull kernel's tiles run over all iterations and phases;
+    ``engine.pull_tiles_real``, the real tiles times iterations; and
+    ``engine.fold_rows``, the rows the level-2 fold combined: the moved
+    rows of every (core, phase) bucket times the pull iterations."""
     with telemetry.span("engine.run"):
         if labels is None:
             labels = prepare_labels(problem, g, pg)
@@ -1032,10 +1029,12 @@ def run(
         # slow) every executable.
         consts = _edge_constants(problem, pg, opts, put=pgw.device_array)
         with telemetry.span("engine.execute"):
-            labels, iters, changed, tiles = _run_jit(
+            labels, iters, changed, tiles, pulls = _run_jit(
                 _wrap(problem), pgw, opts, labels, consts
             )
-            iters, changed, tiles = jax.device_get((iters, changed, tiles))
+            iters, changed, tiles, pulls = jax.device_get(
+                (iters, changed, tiles, pulls)
+            )
         iters = int(iters)
         tiles_run = None
         if opts.backend == "pallas":
@@ -1044,6 +1043,8 @@ def run(
             tiles_run = real if tiles is None else int(tiles)
             telemetry.count("engine.pull_tiles_run", tiles_run)
             telemetry.count("engine.pull_tiles_real", real)
+            pulls = iters if pulls is None else int(pulls)
+            telemetry.count("engine.fold_rows", pulls * pg.split_rows)
         with telemetry.span("engine.readback"):
             out = unpad_labels(labels, pg)
     return EngineResult(

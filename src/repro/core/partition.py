@@ -19,10 +19,11 @@ of the paper's "first log2(p) bits address the core" crossbar routing.
 On top of the (p, l, E_pad) bucket layout, ``partition_2d`` also precomputes
 the COMPRESSED Pallas edge stream the fused engine hot path consumes (paper
 §III: "compressed graph representation"): every (core, phase) bucket is binned
-into (R, T, *tile) row-block edge tiles (``prepare_tiles``) with degree-aware LPT
-row packing, each edge slot's (src, dstb, valid) index triple is bit-packed
-into a single int32 word (``pack_edge_words``), and the words are stacked into
-one (p, l, R, T, *tile) array so a single ``pallas_call`` per phase runs all
+into (R, T, *tile) row-block edge tiles (``prepare_tiles``) that keep every
+natural row in place and move only an overfull block's excess, each edge
+slot's (src, dstb, valid) index triple is bit-packed into a single int32
+word (``pack_edge_words``), and the words are stacked into one
+(p, l, R, T, *tile) array so a single ``pallas_call`` per phase runs all
 cores. ``tile = tile_shape(Eb)`` is one tile's (Eb // c, c) block, c =
 min(Eb, 128) — the block form the TPU compiler accepts (see kernel.py). Packed word format (decoded in-kernel with shifts/masks):
 
@@ -35,9 +36,9 @@ min(Eb, 128) — the block form the TPU compiler accepts (see kernel.py). Packed
 vs 9 B/edge for the uncompressed (int32, int32, bool) triple. ``tile_counts``
 holds the per-(core, phase, row-block) count of REAL edge tiles so the kernel
 skips all-padding tiles outright (variable-T early-out) instead of streaming
-them. ``tile_row_pos`` records the per-bucket row permutation degree-aware
-packing introduced (the engine un-permutes kernel output with one static
-gather).
+them. ``tile_fold_rows`` / ``tile_fold_pos`` name the rows whose edges
+left their block and where the pieces went (the engine folds those few rows
+back after the kernel; every other row is read in place).
 
 Everything here is host-side numpy; outputs are static-shape arrays.
 """
@@ -83,15 +84,13 @@ class PartitionConfig:
     # edge-tile width: below 128, or a multiple of 128 (one tile is a
     # (tile_eb // 128, 128) int32 block; 1024 fills one (8, 128) vreg tile)
     tile_eb: int = 128
-    degree_aware_tiles: bool = True  # LPT row packing (see prepare_tiles)
     pack_src_bits: Optional[int] = None  # force 16/32-bit regime; None = auto
-    # hub-row splitting (two-level reduce): the max edge count of one kernel
-    # row. 'auto' = per bucket max(tile_eb, ceil(E_bucket / R)) — no virtual
-    # row exceeds the mean row-block load, floored at one tile width. An int
-    # fixes the cap for every bucket. None disables splitting entirely (the
-    # pre-split layout is preserved byte-for-byte). Requires
-    # degree_aware_tiles: virtual rows only pay off when the LPT packer can
-    # spread them across row blocks.
+    # row-block capacity (two-level reduce), rounded up to whole tiles: a
+    # natural block above it moves its excess, heaviest rows first, into
+    # appended blocks (see plan_tiles). 'auto' = per bucket max(tile_eb,
+    # ceil(E_bucket / R0)), R0 = Vl / vb natural blocks: the mean block
+    # load, floored at one tile width. An int fixes the capacity for every
+    # bucket. None keeps the identity placement: no block moves anything.
     split_threshold: Union[str, int, None] = "auto"  # 'auto' | int | None
     # push (scatter) direction: a second CSC-style stream of the SAME edges
     # binned by source block so a narrow frontier streams only its own
@@ -141,7 +140,6 @@ class PartitionedGraph:
     tile_word_hi: Optional[np.ndarray] = None  # (p, l, R, T, *tile) int32 (32-bit regime)
     tile_counts: Optional[np.ndarray] = None  # (p, l, R) int32 real tiles per block
     tile_weights: Optional[np.ndarray] = None  # (p, l, R, T, *tile) f32 or None
-    tile_row_pos: Optional[np.ndarray] = None  # (p, l, Vl) int32 or None
     # per-tile source-coverage bitmaps (frontier-aware dynamic skipping):
     # bit j of tile (i, m, r, t)'s word set iff the tile reads a source in
     # frontier word j of phase m's gathered block. Wc = ceil(p * Ws / 32)
@@ -150,14 +148,14 @@ class PartitionedGraph:
     tile_coverage: Optional[np.ndarray] = None  # (p, l, R, T, Wc) uint32
     tile_vb: int = 0  # row-block height (0 = tiles not built)
     src_bits: int = 0  # packed-word regime: 16 or 32 (0 = tiles not built)
-    # hub-row splitting (two-level reduce). When any bucket split a row,
-    # tile_row_pos is None and these take over; R may exceed Vl / vb:
-    # packed kernel-output position -> natural row (-1 = spare, identity):
-    tile_row_orig: Optional[np.ndarray] = None  # (p, l, R * vb) int32
-    # gather form of the same map, what the engine's level-2 combine reads:
-    tile_split_map: Optional[np.ndarray] = None  # (p, l, Vl, S_max) int32, -1 pad
-    split_rows: int = 0  # natural (bucket, row) pairs split into > 1 virtual rows
-    t_max_unsplit: int = 0  # T the stacked stream would need without splitting
+    # the two-level reduce: natural row v's partial is kernel-output row v;
+    # the rows that moved pieces into appended blocks (R may exceed Vl / vb)
+    # and the pieces' packed positions, per bucket, FOLD_PAD-padded. None
+    # when no bucket moved a row (the fold is then the slice alone):
+    tile_fold_rows: Optional[np.ndarray] = None  # (p, l, N) int32
+    tile_fold_pos: Optional[np.ndarray] = None  # (p, l, N, S) int32
+    split_rows: int = 0  # natural (bucket, row) pairs that moved a piece
+    t_max_unsplit: int = 0  # T the stacked stream would need with nothing moved
     # push (scatter) stream: the SAME edge set, re-binned by SOURCE block
     # (B = ceil(gathered_size / push_block) blocks of push_block gathered
     # sources each) so a narrow frontier activates only the blocks that
@@ -248,16 +246,15 @@ class PartitionedGraph:
     @property
     def packed_rows_per_core(self) -> int:
         """Kernel-output rows per core: R * vb. Equals vertices_per_core
-        unless hub-row splitting grew R to make room for virtual rows."""
+        unless an overfull block grew R with appended blocks."""
         if self.tile_word is None:
             return self.vertices_per_core
         return int(self.tile_word.shape[2]) * self.tile_vb
 
     @property
     def split_row_fraction(self) -> float:
-        """Fraction of natural (core, phase, row) slots hub-row splitting
-        broke into > 1 virtual rows (0.0 when splitting is off or no row
-        crossed the threshold)."""
+        """Fraction of natural (core, phase, row) slots that moved a piece
+        out of their block (0.0 when no block was above its capacity)."""
         total = self.p * self.l * self.vertices_per_core
         return self.split_rows / max(total, 1)
 
@@ -273,7 +270,7 @@ class PartitionedGraph:
         device streams exactly its own packed words + tile counts (never the
         flat (l, E_pad) src/dst/valid arrays). Keys match the engine's packed
         edge-constant dict (``word``/``word_hi``/``counts``/``w``/
-        ``row_pos``/``split_map``; absent components are None).
+        ``fold_rows``/``fold_pos``; absent components are None).
 
         ``problem``: when given, the weight stream is dropped unless the
         problem's map UDF consumes it (``edge_op == 'add'``) — the kernel
@@ -295,8 +292,8 @@ class PartitionedGraph:
             "word_hi": self.tile_word_hi,  # (p, l, R, T, *tile) | None
             "counts": self.tile_counts,  # (p, l, R)
             "w": self.tile_weights,  # (p, l, R, T, *tile) f32 | None
-            "row_pos": self.tile_row_pos,  # (p, l, Vl) | None
-            "split_map": self.tile_split_map,  # (p, l, Vl, S_max) | None
+            "fold_rows": self.tile_fold_rows,  # (p, l, N) | None
+            "fold_pos": self.tile_fold_pos,  # (p, l, N, S) | None
             "coverage": self.tile_coverage,  # (p, l, R, T, Wc) u32 | None
             "push_word": self.push_word,  # (p, l, B, Tp, *tile) | None
             "push_word_hi": self.push_word_hi,  # (p, l, B, Tp, *tile) | None
@@ -337,9 +334,9 @@ class PartitionedGraph:
 
     @property
     def t_max_reduction(self) -> float:
-        """Stacked-stream T_max as a fraction of what the UNSPLIT layout
-        would need (the single fattest row block): 1.0 = splitting off or
-        no effect; the acceptance target on star-like graphs is <= 0.5."""
+        """Stacked-stream T_max as a fraction of what the identity placement
+        would need (the single fattest natural row block): 1.0 = nothing
+        moved; on star-like graphs it is <= 0.5."""
         if self.tile_word is None or self.t_max_unsplit <= 0:
             return 1.0
         return float(self.tile_word.shape[3]) / float(self.t_max_unsplit)
@@ -382,9 +379,8 @@ class PartitionedGraph:
         memmap-backed build keeps them on disk)."""
         device_fields = (
             "tile_word", "tile_word_hi", "tile_counts", "tile_weights",
-            "tile_coverage", "tile_row_pos", "tile_row_orig",
-            "tile_split_map", "push_word", "push_word_hi", "push_counts",
-            "push_weights", "push_coverage",
+            "tile_coverage", "tile_fold_rows", "tile_fold_pos", "push_word",
+            "push_word_hi", "push_counts", "push_weights", "push_coverage",
         )
         flat_fields = ("src_gidx", "dst_lidx", "valid", "weights")
         device = {
@@ -534,13 +530,12 @@ def partition_2d(g: COOGraph, cfg: PartitionConfig) -> PartitionedGraph:
 
 
 def _bucket_split_threshold(cfg: PartitionConfig, bucket_edges: int, r_blocks: int):
-    """Resolve cfg.split_threshold for one bucket (None = splitting off)."""
-    if cfg.split_threshold is None or not cfg.degree_aware_tiles:
+    """Resolve cfg.split_threshold for one bucket (None = identity placement)."""
+    if cfg.split_threshold is None:
         return None
     if cfg.split_threshold == "auto":
-        # cap every kernel row at the bucket's MEAN row-block load (a row at
-        # the mean cannot raise T above it) but never below one tile width —
-        # sub-tile chunks cost R without shrinking T.
+        # the bucket's MEAN natural-block load, so the natural blocks hold
+        # the bucket but for their overflow — never below one tile width
         return max(cfg.tile_eb, -(-int(bucket_edges) // max(r_blocks, 1)))
     return int(cfg.split_threshold)
 
@@ -550,15 +545,15 @@ def _build_tile_layouts(p, l, vpc, src_gidx, dst_lidx, valid, weights, cfg, sub_
     each slot's index triple into the compressed word stream, and stack to
     (p, l, R, T, *tile) with uniform (R, T) (max over buckets; padded tiles are
     recorded in ``tile_counts`` so the kernel skips them) so the engine
-    launches all cores of a phase in one pallas_call. Hub rows above the
-    split threshold become virtual rows (see prepare_tiles); when any bucket
-    split, ``tile_row_orig``/``tile_split_map`` replace ``tile_row_pos`` and
-    the engine runs the two-level reduce."""
+    launches all cores of a phase in one pallas_call. A block above its
+    capacity moves its excess into appended blocks (see plan_tiles); the
+    moved rows and their pieces' positions are stacked into
+    ``tile_fold_rows``/``tile_fold_pos`` for the engine's level-2 fold."""
     from repro.kernels.csr_gather_reduce.ops import (
         choose_src_bits,
         prepare_push_tiles,
         prepare_tiles,
-        split_map_from_row_orig,
+        stack_fold_arrays,
         stack_packed_tiles,
         stack_push_tiles,
         tile_coverage_words,
@@ -579,7 +574,6 @@ def _build_tile_layouts(p, l, vpc, src_gidx, dst_lidx, valid, weights, cfg, sub_
                 src_gidx[i, m], dst_lidx[i, m], valid[i, m],
                 num_rows=vpc, vb=vb, eb=eb,
                 weights=weights[i, m] if weights is not None else None,
-                balance_rows=cfg.degree_aware_tiles,
                 split_threshold=_bucket_split_threshold(
                     cfg, int(valid[i, m].sum()), vpc // vb
                 ),
@@ -603,44 +597,9 @@ def _build_tile_layouts(p, l, vpc, src_gidx, dst_lidx, valid, weights, cfg, sub_
     tile_coverage = tile_coverage_words(
         tile_word, tile_word_hi, src_bits=src_bits, p=p, sub_size=sub_size
     )
-    any_split = any(t.row_orig is not None for row in layouts for t in row)
-    tile_row_pos = tile_row_orig = tile_split_map = None
-    split_rows = 0
-    t_max_unsplit = max(t.t_tiles_unsplit for t in flat)
-    if any_split:
-        # every bucket needs a row_orig map (split or not) so one uniform
-        # (p, l, Vl, S_max) gather drives the engine's level-2 combine.
-        packed_rows = r_blocks * vb
-        tile_row_orig = np.full((p, l, packed_rows), -1, dtype=np.int32)
-        maps = []
-        for i in range(p):
-            for m in range(l):
-                t = layouts[i][m]
-                if t.row_orig is not None:
-                    ro = t.row_orig
-                elif t.row_pos is not None:
-                    ro = np.full(vpc, -1, dtype=np.int32)
-                    ro[t.row_pos] = np.arange(vpc, dtype=np.int32)
-                else:
-                    ro = np.arange(vpc, dtype=np.int32)
-                tile_row_orig[i, m, : ro.shape[0]] = ro
-                maps.append(split_map_from_row_orig(tile_row_orig[i, m], vpc))
-                split_rows += t.num_split_rows
-        s_max = max(sm.shape[1] for sm in maps)
-        tile_split_map = np.full((p, l, vpc, s_max), -1, dtype=np.int32)
-        for b, sm in enumerate(maps):
-            tile_split_map[b // l, b % l, :, : sm.shape[1]] = sm
-    else:
-        any_packed = any(t.row_pos is not None for row in layouts for t in row)
-        tile_row_pos = (
-            np.tile(np.arange(vpc, dtype=np.int32), (p, l, 1)) if any_packed else None
-        )
-        if tile_row_pos is not None:
-            for i in range(p):
-                for m in range(l):
-                    t = layouts[i][m]
-                    if t.row_pos is not None:
-                        tile_row_pos[i, m] = t.row_pos
+    fold_rows, fold_pos = stack_fold_arrays(
+        [t.fold_rows for t in flat], [t.fold_pos for t in flat], (p, l)
+    )
     push = {}
     if cfg.build_push:
         # push (scatter) stream: same edges, binned by SOURCE block. The
@@ -706,14 +665,13 @@ def _build_tile_layouts(p, l, vpc, src_gidx, dst_lidx, valid, weights, cfg, sub_
         tile_word_hi=tile_word_hi,
         tile_counts=tile_counts,
         tile_weights=tile_weights,
-        tile_row_pos=tile_row_pos,
         tile_coverage=tile_coverage,
         tile_vb=vb,
         src_bits=src_bits,
-        tile_row_orig=tile_row_orig,
-        tile_split_map=tile_split_map,
-        split_rows=split_rows,
-        t_max_unsplit=t_max_unsplit,
+        tile_fold_rows=fold_rows,
+        tile_fold_pos=fold_pos,
+        split_rows=sum(t.num_split_rows for t in flat),
+        t_max_unsplit=max(t.t_tiles_unsplit for t in flat),
         **push,
     )
 
@@ -799,8 +757,8 @@ def partition_2d_streaming(
         per-source counts are accumulated chunk by chunk — O(p·l·Vl) state,
         independent of E. From the counts alone, ``plan_tiles`` /
         ``plan_push_tiles`` fix every layout decision (src_bits regime,
-        per-bucket 'auto' split thresholds, hub-row chunking, LPT placement,
-        stacked R/T/B/Tp, row-map mode) and the full output buffers are
+        per-bucket 'auto' capacities, which rows move and where their pieces
+        go, stacked R/T/N/S/B/Tp) and the full output buffers are
         preallocated — optionally ``np.memmap``-backed under ``memmap_dir``.
 
       pass 2 (place): each chunk is binned straight into the preallocated
@@ -870,8 +828,8 @@ def partition_2d_streaming(
         total += int(s.size)
     weighted = bool(weighted)
 
-    # ---- plan: every shape decision from counts alone (plan_tiles mirrors
-    # prepare_tiles bit for bit — same thresholds, chunking, LPT placement).
+    # ---- plan: every shape decision from counts alone (plan_tiles is what
+    # prepare_tiles places edges by — same capacities, moved rows, pieces).
     e_pad = max(_round_up(int(sizes.max()), cfg.edge_pad), cfg.edge_pad)
 
     if memmap_dir is not None:
@@ -895,6 +853,7 @@ def partition_2d_streaming(
             choose_src_bits,
             plan_push_tiles,
             plan_tiles,
+            stack_fold_arrays,
             tile_shape,
         )
 
@@ -910,7 +869,6 @@ def partition_2d_streaming(
             for m in range(l):
                 plans[(i, m)] = plan_tiles(
                     row_counts[i, m], num_rows=vpc, vb=vb, eb=eb,
-                    balance_rows=cfg.degree_aware_tiles,
                     split_threshold=_bucket_split_threshold(
                         cfg, int(sizes[i, m]), vpc // vb
                     ),
@@ -934,21 +892,11 @@ def partition_2d_streaming(
         tile_coverage = _alloc(
             "tile_coverage", (p, l, r_max, t_max, wc), np.uint32
         )
-        # row-map mode is a GLOBAL property, decidable from the plans before
-        # a single edge is placed (cold-path rule: any split bucket => every
-        # bucket runs in row_orig/split-map mode).
-        any_split = any(pl.row_orig is not None for pl in plans.values())
-        tile_row_pos = tile_row_orig = tile_split_map = None
-        if any_split:
-            tile_row_orig = np.full((p, l, r_max * vb), -1, dtype=np.int32)
-            s_max = max(pl.s_max for pl in plans.values())
-            tile_split_map = np.full((p, l, vpc, s_max), -1, dtype=np.int32)
-        else:
-            any_packed = any(pl.row_pos is not None for pl in plans.values())
-            if any_packed:
-                tile_row_pos = np.tile(
-                    np.arange(vpc, dtype=np.int32), (p, l, 1)
-                )
+        flat_plans = [plans[(i, m)] for i in range(p) for m in range(l)]
+        tile_fold_rows, tile_fold_pos = stack_fold_arrays(
+            [pl.fold_rows for pl in flat_plans],
+            [pl.fold_pos for pl in flat_plans], (p, l),
+        )
         push_shapes = None
         if cfg.build_push:
             push_src_bits = (
@@ -1039,10 +987,8 @@ def partition_2d_streaming(
             pack_edge_words,
             prepare_push_tiles,
             prepare_tiles,
-            split_map_from_row_orig,
             tile_coverage_words,
         )
-    split_rows = 0
     for i in range(p):
         for m in range(l):
             n = int(sizes[i, m])
@@ -1062,10 +1008,6 @@ def partition_2d_streaming(
                 src_gidx[i, m], dst_lidx[i, m], valid[i, m],
                 num_rows=vpc, vb=vb, eb=eb,
                 weights=weights[i, m] if weights is not None else None,
-                balance_rows=cfg.degree_aware_tiles,
-                split_threshold=_bucket_split_threshold(
-                    cfg, n, vpc // vb
-                ),
                 plan=plan,
             )
             rr, tt = t.src.shape[:2]
@@ -1086,20 +1028,6 @@ def partition_2d_streaming(
                 else None,
                 src_bits=src_bits, p=p, sub_size=sub_size,
             )
-            if any_split:
-                if t.row_orig is not None:
-                    ro = t.row_orig
-                elif t.row_pos is not None:
-                    ro = np.full(vpc, -1, dtype=np.int32)
-                    ro[t.row_pos] = np.arange(vpc, dtype=np.int32)
-                else:
-                    ro = np.arange(vpc, dtype=np.int32)
-                tile_row_orig[i, m, : ro.shape[0]] = ro
-                sm = split_map_from_row_orig(tile_row_orig[i, m], vpc)
-                tile_split_map[i, m, :, : sm.shape[1]] = sm
-                split_rows += t.num_split_rows
-            elif tile_row_pos is not None and t.row_pos is not None:
-                tile_row_pos[i, m] = t.row_pos
             if cfg.build_push:
                 pt = prepare_push_tiles(
                     src_gidx[i, m], dst_lidx[i, m], valid[i, m],
@@ -1134,14 +1062,13 @@ def partition_2d_streaming(
             tile_word_hi=tile_word_hi,
             tile_counts=tile_counts,
             tile_weights=tile_weights,
-            tile_row_pos=tile_row_pos,
             tile_coverage=tile_coverage,
             tile_vb=vb,
             src_bits=src_bits,
-            tile_row_orig=tile_row_orig,
-            tile_split_map=tile_split_map,
-            split_rows=split_rows,
-            t_max_unsplit=max(pl.t_tiles_unsplit for pl in plans.values()),
+            tile_fold_rows=tile_fold_rows,
+            tile_fold_pos=tile_fold_pos,
+            split_rows=sum(pl.fold_rows.shape[0] for pl in flat_plans),
+            t_max_unsplit=max(pl.t_tiles_unsplit for pl in flat_plans),
         )
         if cfg.build_push:
             tiles.update(
@@ -1195,7 +1122,6 @@ class DeltaFlushReport:
     tile_bytes_total: int
     grew_edge_pad: bool  # per-bucket flat arrays grew past the old E_pad
     grew_tiles: bool  # stacked R/T/Tp grew (clean slices padded, not rebuilt)
-    mode_changed: bool  # row-map mode flipped (row_pos -> split map)
 
     @property
     def repacked_fraction(self) -> float:
@@ -1292,7 +1218,7 @@ def apply_edge_deltas(
         return pg, DeltaFlushReport(
             dirty=(), buckets_retiled=0, total_buckets=p * l, edges_added=0,
             tile_bytes_repacked=0, tile_bytes_total=_tile_bytes_total(pg),
-            grew_edge_pad=False, grew_tiles=False, mode_changed=False,
+            grew_edge_pad=False, grew_tiles=False,
         )
     core, phase, gidx, lidx = bucket_coords(pg, src, dst)
     w = np.asarray(weights, dtype=np.float32) if weights is not None else None
@@ -1349,15 +1275,14 @@ def apply_edge_deltas(
     )
     rep_bytes = 0
     grew_tiles = False
-    mode_changed = False
 
     if pg.tile_word is not None:
         from repro.kernels.csr_gather_reduce.ops import (
-            _lpt_max_load,
+            FOLD_PAD,
             pack_edge_words,
             prepare_push_tiles,
             prepare_tiles,
-            split_map_from_row_orig,
+            stack_fold_arrays,
             tile_coverage_words,
         )
 
@@ -1371,29 +1296,27 @@ def apply_edge_deltas(
                 src_gidx[i, m], dst_lidx[i, m], valid[i, m],
                 num_rows=vpc, vb=vb, eb=eb,
                 weights=wts_flat[i, m] if wts_flat is not None else None,
-                balance_rows=cfg.degree_aware_tiles,
                 split_threshold=_bucket_split_threshold(
                     cfg, int(new_sizes[i, m]), vpc // vb
                 ),
             )
             for (i, m) in dirty
         }
-        # Per-bucket layout shape + split metadata. The stacked shape is the
+        # Per-bucket layout shape + fold metadata. The stacked shape is the
         # GLOBAL max over buckets — it can also SHRINK: a dirty bucket that
-        # dictated the old R/T/S_max re-tiles under a larger 'auto' split
-        # threshold (it grows with the bucket's edge count) and may need
-        # less. Clean buckets' contributions are derived without touching
-        # their packed bytes: T from the valid sign bits (a real tile always
-        # holds >= 1 valid edge, and tiles fill a row block in order), R and
-        # S from the row maps, and the unsplit-T metric from the flat dst
-        # column — metadata reads, not stream rebuilds.
+        # dictated the old R/T/N/S re-tiles under a larger 'auto' capacity
+        # (it grows with the bucket's edge count) and may need less. Clean
+        # buckets' contributions are read without touching their packed
+        # bytes: T from the valid sign bits (a real tile always holds >= 1
+        # valid edge, and tiles fill a row block in order), R and the moved
+        # rows from the fold arrays, and the identity-placement T from the
+        # flat dst column — metadata reads, not stream rebuilds.
         vword = pg.tile_word_hi if pg.tile_word_hi is not None else pg.tile_word
         tile_has_edge = (vword < 0).any(axis=(2, 4, 5))  # (p, l, T)
         r_b = np.full((p, l), r_base, dtype=np.int64)
         t_b = np.ones((p, l), dtype=np.int64)
-        s_b = np.ones((p, l), dtype=np.int64)  # split-map width per bucket
-        split_b = np.zeros((p, l), dtype=np.int64)  # split natural rows
-        tu_b = np.ones((p, l), dtype=np.int64)  # per-bucket unsplit T
+        tu_b = np.ones((p, l), dtype=np.int64)  # per-bucket identity T
+        fold_rows_b, fold_pos_b = {}, {}
         for i in range(p):
             for m in range(l):
                 if (i, m) in layouts:
@@ -1401,24 +1324,24 @@ def apply_edge_deltas(
                 nz = np.nonzero(tile_has_edge[i, m])[0]
                 if nz.size:
                     t_b[i, m] = int(nz[-1]) + 1
-                if pg.tile_split_map is not None:
-                    width = (pg.tile_split_map[i, m] >= 0).sum(axis=1)
-                    s_b[i, m] = max(int(width.max()), 1)
-                    split_b[i, m] = int((width > 1).sum())
-                    pos = np.nonzero(pg.tile_row_orig[i, m] >= 0)[0]
-                    if pos.size:
-                        r_b[i, m] = max(r_base, int(pos[-1]) // vb + 1)
+                rows, pos = np.zeros(0, np.int32), np.zeros((0, 0), np.int32)
+                real = (
+                    pg.tile_fold_rows[i, m] != FOLD_PAD
+                    if pg.tile_fold_rows is not None else np.zeros(0, bool)
+                )
+                if real.any():
+                    rows, pos = pg.tile_fold_rows[i, m, real], pg.tile_fold_pos[i, m, real]
+                    pos = pos[:, : int((pos != FOLD_PAD).sum(axis=1).max())]
+                    r_b[i, m] = int(pos[pos != FOLD_PAD].max()) // vb + 1
+                fold_rows_b[(i, m)], fold_pos_b[(i, m)] = rows, pos
                 n_old = int(pg.bucket_sizes[i, m])
                 rc = np.bincount(pg.dst_lidx[i, m, :n_old], minlength=vpc)
-                if cfg.degree_aware_tiles:
-                    load = _lpt_max_load(rc, r_base, vb)
-                else:
-                    load = int(rc.reshape(r_base, vb).sum(axis=1).max())
-                tu_b[i, m] = max(1, -(-int(load) // eb))
+                load = int(rc.reshape(r_base, vb).sum(axis=1).max())
+                tu_b[i, m] = max(1, -(-load // eb))
         for (i, m), t in layouts.items():
             r_b[i, m], t_b[i, m] = t.src.shape[0], t.src.shape[1]
             tu_b[i, m] = t.t_tiles_unsplit
-            split_b[i, m] = t.num_split_rows
+            fold_rows_b[(i, m)], fold_pos_b[(i, m)] = t.fold_rows, t.fold_pos
         r_new, t_new = int(r_b.max()), int(t_b.max())
         grew_tiles = (r_new, t_new) != (r_old, t_old)
         ro_n, to_n = min(r_old, r_new), min(t_old, t_new)
@@ -1469,91 +1392,21 @@ def apply_edge_deltas(
                 tile_coverage[i, m] = cov
                 rep_bytes += cov.nbytes
 
-        # -- row maps: dirty buckets bring fresh maps; clean buckets keep
-        # (or mechanically re-derive — metadata, not packed stream) theirs.
-        # The MODE is a global property: a partition is in split mode iff ANY
-        # bucket still has a split row, so it can flip in either direction —
-        # pos->split when a dirty bucket crosses its threshold, split->pos
-        # when the only split bucket un-splits under its grown threshold.
-        any_split_old = pg.tile_split_map is not None
-        any_split_new = bool((split_b > 0).any())
-        mode_changed = any_split_old != any_split_new
-        tile_row_pos = tile_row_orig = tile_split_map = None
-        split_rows = 0
-        if not any_split_new:
-            # no virtual rows anywhere: R stays Vl / vb in this mode, and the
-            # pos map exists iff the LPT packer ran (cold-path rule)
-            if cfg.degree_aware_tiles and r_base > 1:
-                tile_row_pos = np.tile(np.arange(vpc, dtype=np.int32), (p, l, 1))
-                for i in range(p):
-                    for m in range(l):
-                        if (i, m) in layouts:
-                            t = layouts[(i, m)]
-                            if t.row_pos is not None:
-                                tile_row_pos[i, m] = t.row_pos
-                        elif pg.tile_row_pos is not None:
-                            tile_row_pos[i, m] = pg.tile_row_pos[i, m]
-                        elif pg.tile_row_orig is not None:
-                            # split->pos flip: invert the clean bucket's
-                            # packed-position map (it has no split rows, so
-                            # the inverse is exactly the row_pos the cold
-                            # LPT pass reproduces on unchanged row counts)
-                            pos = np.nonzero(pg.tile_row_orig[i, m] >= 0)[0]
-                            tile_row_pos[
-                                i, m, pg.tile_row_orig[i, m, pos]
-                            ] = pos.astype(np.int32)
-        else:
-            packed_old, packed_new = r_old * vb, r_new * vb
-            po_n = min(packed_old, packed_new)
-            tile_row_orig = np.full((p, l, packed_new), -1, dtype=np.int32)
-            if pg.tile_row_orig is not None:
-                tile_row_orig[:, :, :po_n] = pg.tile_row_orig[:, :, :po_n]
-            elif pg.tile_row_pos is not None:
-                for i in range(p):
-                    for m in range(l):
-                        tile_row_orig[i, m, pg.tile_row_pos[i, m]] = np.arange(
-                            vpc, dtype=np.int32
-                        )
-            else:
-                tile_row_orig[:, :, :vpc] = np.arange(vpc, dtype=np.int32)
-            for (i, m), t in layouts.items():
-                ro = np.full(packed_new, -1, dtype=np.int32)
-                if t.row_orig is not None:
-                    ro[: t.row_orig.shape[0]] = t.row_orig
-                elif t.row_pos is not None:
-                    ro[t.row_pos] = np.arange(vpc, dtype=np.int32)
-                else:
-                    ro[:vpc] = np.arange(vpc, dtype=np.int32)
-                tile_row_orig[i, m] = ro
-            # gather-form split maps: rebuild dirty buckets (and every bucket
-            # on a pos->split mode flip, where no old map exists)
-            maps = {}
-            for i in range(p):
-                for m in range(l):
-                    if (i, m) in layouts or not any_split_old:
-                        maps[(i, m)] = split_map_from_row_orig(
-                            tile_row_orig[i, m], vpc
-                        )
-                        s_b[i, m] = maps[(i, m)].shape[1]
-            s_max = int(s_b.max())
-            tile_split_map = np.full((p, l, vpc, s_max), -1, dtype=np.int32)
-            if any_split_old:
-                so_n = min(pg.tile_split_map.shape[3], s_max)
-                tile_split_map[:, :, :, :so_n] = pg.tile_split_map[:, :, :, :so_n]
-            for (i, m), sm in maps.items():
-                tile_split_map[i, m] = -1
-                tile_split_map[i, m, :, : sm.shape[1]] = sm
-            split_rows = int(split_b.sum())
+        # -- fold arrays: dirty buckets bring fresh ones, clean buckets keep
+        # theirs; both restacked to the new maxima (metadata, not stream)
+        keys = [(i, m) for i in range(p) for m in range(l)]
+        tile_fold_rows, tile_fold_pos = stack_fold_arrays(
+            [fold_rows_b[k] for k in keys], [fold_pos_b[k] for k in keys], (p, l)
+        )
         updates.update(
             tile_word=tile_word,
             tile_word_hi=tile_word_hi,
             tile_counts=tile_counts,
             tile_weights=tile_weights,
             tile_coverage=tile_coverage,
-            tile_row_pos=tile_row_pos,
-            tile_row_orig=tile_row_orig,
-            tile_split_map=tile_split_map,
-            split_rows=split_rows,
+            tile_fold_rows=tile_fold_rows,
+            tile_fold_pos=tile_fold_pos,
+            split_rows=sum(r.shape[0] for r in fold_rows_b.values()),
             t_max_unsplit=int(tu_b.max()),
         )
 
@@ -1641,7 +1494,6 @@ def apply_edge_deltas(
         tile_bytes_total=_tile_bytes_total(new_pg),
         grew_edge_pad=grew_epad,
         grew_tiles=grew_tiles,
-        mode_changed=mode_changed,
     )
     return new_pg, report
 

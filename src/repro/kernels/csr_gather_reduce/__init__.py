@@ -11,6 +11,6 @@ from repro.kernels.csr_gather_reduce.ops import (  # noqa: F401
     pack_edge_words,
     prepare_tiles,
     segment_reduce_rows,
-    split_map_from_row_orig,
+    stack_fold_arrays,
     stack_packed_tiles,
 )

@@ -330,14 +330,14 @@ def gather_reduce_cores_pallas(
     the scratch pad and one row block's accumulator, SMEM one edge tile per
     operand.
 
-    Hub-row splitting (two-level reduce): output rows may be VIRTUAL — a
-    partition-time split of one natural hub row into even chunks, each packed
-    into its own slot so no single row block carries the whole hub and T_max
-    stays near the mean block load. The kernel is oblivious: it reduces each
-    packed row independently (level 1; rows it never touches keep the
-    ``identity`` written at t == 0, which is what makes spare slots safe for
-    the combine). The engine folds the partials into natural rows afterwards
-    with the problem's reduce op (level 2, ``combine_split_rows``).
+    Two-level reduce: output rows are PACKED rows — the natural rows in
+    place, then any blocks appended at partition time for the pieces of rows
+    whose block was over its capacity, so no row block carries more than
+    the capacity and T_max stays near the mean block load. The kernel is
+    oblivious: it reduces each packed row independently (level 1; rows it
+    never touches keep the ``identity`` written at t == 0). The engine
+    folds the pieces into their natural rows afterwards with the problem's
+    reduce op (level 2, ``combine_split_rows``).
 
     Frontier-aware dynamic skipping: passing ``fetch`` (a traced (p, R, T)
     int32 map, ``core.frontier_words.active_fetch_map`` of this iteration's
@@ -396,8 +396,8 @@ def scatter_reduce_cores_pallas(
     rely on their contributions being already merged; both hold for min/or,
     neither for sum (docs/tile_layout.md §9).
 
-    There is no level-2 fold here: hub-row splitting is a pull-layout
-    construct, and the push accumulator's rows are natural rows by
+    There is no level-2 fold here: moving rows out of a block is a
+    pull-layout construct, and the push accumulator's rows are natural rows by
     construction, so the engine consumes this output directly.
     """
     assert kind in ("min", "or"), f"push scatter requires min/or, got {kind!r}"

@@ -1,16 +1,18 @@
 """Host-side tile preparation + jitted wrapper for the accumulator kernel.
 
 ``prepare_tiles`` bins a (dst-sorted) edge bucket into (R, T, Eb) row-block
-tiles at partition time (numpy). With ``split_threshold`` set it also SPLITS
-hub rows whose edge count exceeds the threshold into multiple *virtual rows*
-(even chunks) before LPT packing, so a single fat row no longer sets T for
-the whole bucket; the kernel then reduces each virtual row independently
-(level 1) and ``combine_split_rows`` merges the virtual-row partials back
-into true rows with the problem's reduce op (level 2) — the TPU analogue of
-the paper's two-level crossbar absorbing power-law skew. ``pack_edge_words``
-bit-packs the (src, dstb, valid) index triple of each edge slot into the
-compressed word stream the fused engine path reads (see ``kernel.py`` for the
-word format and ``choose_src_bits`` for the 16/32-bit regime rule).
+tiles at partition time (numpy). Every natural row keeps its own output
+position; with ``split_threshold`` set, a row block holding more than that
+capacity moves its excess (its heaviest rows' edges) into appended blocks,
+so no block, and so no T, exceeds the capacity however skewed the rows. The
+kernel reduces every packed row independently (level 1) and
+``combine_split_rows`` folds the moved pieces back into their rows with the
+problem's reduce op (level 2), touching only the rows that moved: the TPU
+analogue of the paper's two-level crossbar absorbing power-law skew.
+``pack_edge_words`` bit-packs the (src, dstb, valid) index triple of each
+edge slot into the compressed word stream the fused engine path reads (see
+``kernel.py`` for the word format and ``choose_src_bits`` for the 16/32-bit
+regime rule).
 ``gather_reduce`` runs the Pallas kernel; ``segment_reduce_rows`` is the
 reduce-only variant used when contributions are already materialized (engine
 fallback path).
@@ -39,7 +41,7 @@ __all__ = [
     "stack_packed_tiles",
     "stack_push_tiles",
     "tile_coverage_words",
-    "split_map_from_row_orig",
+    "stack_fold_arrays",
     "combine_split_rows",
     "gather_reduce",
     "segment_reduce_rows",
@@ -48,6 +50,9 @@ __all__ = [
 # packed-word field bounds (see kernel.py "Compressed edge stream" docstring)
 SRC16_LIMIT = 1 << 16  # gathered-block offsets that fit the 16-bit src field
 DSTB16_LIMIT = 1 << 15  # row-block offsets that fit next to a 16-bit src
+# fold-array padding: past the end of every axis it indexes, so a gather
+# reads the fill value and a scatter drops it (a negative index would wrap)
+FOLD_PAD = np.iinfo(np.int32).max
 
 
 def choose_src_bits(gathered_size: int, vb: int) -> int:
@@ -302,13 +307,14 @@ def stack_push_tiles(
 class TileLayout:
     """(R, T, Eb) row-block binned edges; padding slots have valid=False.
 
-    With hub-row splitting engaged (``row_orig`` set) the R*vb kernel-output
-    positions hold VIRTUAL rows: a natural row above the split threshold owns
-    several of them, each reduced independently by the kernel, and R may
-    exceed ``num_rows / vb``. ``row_orig`` maps every packed position back to
-    its natural row (-1 = spare slot, holds the reduce identity); the
-    second-level combine (``combine_split_rows``) folds the partials together.
-    ``row_pos`` and ``row_orig`` are mutually exclusive.
+    Natural row ``v`` owns kernel-output position ``v``: blocks ``0 ..
+    num_rows / vb - 1`` are the natural blocks. A natural block that holds
+    more edges than the bucket's capacity C moves its excess, heaviest rows
+    first, into appended blocks (R then exceeds ``num_rows / vb``); each
+    moved row keeps its first edges in place and sends the rest as pieces of
+    at most C edges. ``fold_rows`` / ``fold_pos`` name those rows and their
+    pieces' packed positions, and ``combine_split_rows`` folds the pieces'
+    partials back into the rows (level 2). Both are empty when nothing moved.
     """
 
     src: np.ndarray  # (R, T, Eb) int32
@@ -316,24 +322,27 @@ class TileLayout:
     valid: np.ndarray  # (R, T, Eb) bool
     weights: np.ndarray | None  # (R, T, Eb) f32
     vb: int
-    num_rows: int  # NATURAL rows (combine output size); packed rows = R * vb
+    num_rows: int  # NATURAL rows (fold output size); packed rows = R * vb
     # slot -> index into the ORIGINAL (pre-binning) edge arrays, 0 on padding.
     # Lets runtime-traced per-edge values (e.g. GAT scores) be laid out into
     # tile order with one static gather.
     gather_idx: np.ndarray | None = None  # (R, T, Eb) int64
-    # degree-aware packing: natural row i's reduction lives at kernel-output
-    # position row_pos[i] (None = identity layout). Undo with out[row_pos].
-    row_pos: np.ndarray | None = None  # (num_rows,) int32
     # real edge tiles per row block: ceil(real_edges[r] / Eb). Tiles with
     # t >= tile_counts[r] are all-padding; the fused kernel skips them.
     tile_counts: np.ndarray | None = None  # (R,) int32
-    # hub-row splitting (level-2 reduce): packed position -> natural row
-    # (-1 = spare slot carrying the reduce identity). None = no row was split.
-    row_orig: np.ndarray | None = None  # (R * vb,) int32
-    num_split_rows: int = 0  # natural rows split into > 1 virtual rows
-    # T this bucket would have needed WITHOUT splitting (== own T when no row
-    # was split) — the denominator of the t_max_reduction metric.
+    # natural rows that moved a piece (ascending) and each one's pieces'
+    # packed positions, FOLD_PAD-padded: (N,) and (N, S), N = S = 0 when no
+    # block overflowed
+    fold_rows: np.ndarray | None = None  # (N,) int32
+    fold_pos: np.ndarray | None = None  # (N, S) int32
+    # T of the identity placement (the natural blocks' own loads) — the
+    # denominator of the t_max_reduction metric
     t_tiles_unsplit: int = 0
+
+    @property
+    def num_split_rows(self) -> int:
+        """Natural rows that moved a piece out of their block."""
+        return 0 if self.fold_rows is None else int(self.fold_rows.shape[0])
 
     @property
     def tile_padding_ratio(self) -> float:
@@ -341,65 +350,50 @@ class TileLayout:
         return 1.0 - float(self.valid.sum()) / max(total, 1)
 
 
-def _balance_row_blocks(row_counts: np.ndarray, r_blocks: int, vb: int) -> np.ndarray:
-    """LPT row->block assignment: rows sorted by in-degree, each placed in the
-    least-loaded block with a free slot. Minimizes the max per-block edge count
-    so one hub row no longer inflates T for EVERY row block. Returns row_pos
-    (natural row -> packed output position)."""
-    order = np.argsort(-row_counts, kind="stable")
-    load = np.zeros(r_blocks, dtype=np.int64)
-    slots = np.zeros(r_blocks, dtype=np.int64)
-    row_pos = np.empty(row_counts.shape[0], dtype=np.int32)
-    full = np.int64(np.iinfo(np.int64).max)
-    for row in order:
-        cand = np.where(slots < vb, load, full)
-        b = int(cand.argmin())
-        row_pos[row] = b * vb + slots[b]
+def _pack_pieces(sizes: np.ndarray, cap: int, vb: int) -> np.ndarray:
+    """LPT over the moved pieces: heaviest first, each into the least-loaded
+    appended block that still has room for it (load <= ``cap`` edges, at most
+    ``vb`` rows), a new block when none has. Returns each piece's packed
+    position relative to the first appended block."""
+    pos = np.empty(sizes.shape[0], dtype=np.int64)
+    load = np.zeros(sizes.shape[0], dtype=np.int64)
+    slots = np.zeros(sizes.shape[0], dtype=np.int64)
+    opened = 0
+    for i in np.argsort(-sizes, kind="stable"):
+        size = int(sizes[i])
+        fit = (slots[:opened] < vb) & (load[:opened] + size <= cap)
+        if fit.any():
+            b = int(np.where(fit, load[:opened], cap + 1).argmin())
+        else:
+            b, opened = opened, opened + 1
+        pos[i] = b * vb + slots[b]
         slots[b] += 1
-        load[b] += row_counts[row]
-    return row_pos
-
-
-def _lpt_max_load(row_counts: np.ndarray, r_blocks: int, vb: int) -> int:
-    """Max per-block edge load the LPT packer achieves WITHOUT splitting."""
-    if r_blocks <= 1:
-        return int(row_counts.sum())
-    pos = _balance_row_blocks(row_counts, r_blocks, vb)
-    loads = np.bincount(pos // vb, weights=row_counts.astype(np.float64),
-                        minlength=r_blocks)
-    return int(loads.max())
+        load[b] += size
+    return pos
 
 
 @dataclasses.dataclass(frozen=True)
 class TilePlan:
-    """Shape + row-map decisions of one bucket's tile layout, computed from
-    the per-row edge counts ALONE — no edge data needed.
+    """Shape + row-placement decisions of one bucket's tile layout, computed
+    from the per-row edge counts ALONE — no edge data needed.
 
     This is the single source of truth for everything about a bucket's layout
     that does not depend on which concrete edges fill the slots: the
     out-of-core streaming partitioner (``partition_2d_streaming``) calls
     ``plan_tiles`` during its counting pass to pre-size the stacked packed
     buffers before any edge is placed, and ``prepare_tiles`` consumes the
-    same plan to place edges — so the two paths cannot disagree on shapes,
-    split chunking, or row placement. A natural row with ``count`` edges and
-    ``k = n_chunks[row]`` virtual rows splits into even chunks whose sizes
-    are fully determined by (count, k): chunk ``c`` holds the edges ``j``
-    with ``j * k // count == c``, i.e. ``ceil((c+1)*count/k) -
-    ceil(c*count/k)`` edges — what ``virt_counts`` records.
+    same plan to place edges — so the two paths cannot disagree on shapes or
+    placement. Moved row ``fold_rows[n]`` keeps its first ``count -
+    fold_moved[n]`` edges at its own position and splits the rest into even
+    pieces whose positions ``fold_pos[n]`` lists.
     """
 
-    r_blocks: int  # row blocks (>= num_rows/vb when virtual rows need room)
+    r_blocks: int  # natural blocks + appended overflow blocks
     t_tiles: int  # max real edge tiles over the row blocks
-    t_tiles_unsplit: int  # T without splitting (== t_tiles when no split)
-    num_split_rows: int  # natural rows split into > 1 virtual rows
-    s_max: int  # split-map width: max virtual rows per natural row (>= 1)
-    # exactly one of row_pos / row_orig is set when the layout is non-trivial:
-    row_pos: np.ndarray | None  # (num_rows,) natural row -> packed position
-    row_orig: np.ndarray | None  # (r_blocks * vb,) packed position -> row
-    # split-mode edge-placement inputs (None when no row split):
-    n_chunks: np.ndarray | None  # (num_rows,) virtual rows per natural row
-    virt_base: np.ndarray | None  # (num_rows,) first virtual-row id per row
-    virt_pos: np.ndarray | None  # (num_virtual,) virtual row -> packed pos
+    t_tiles_unsplit: int  # T of the identity placement
+    fold_rows: np.ndarray  # (N,) int32 natural rows that moved a piece
+    fold_moved: np.ndarray  # (N,) int64 edges each of them moved
+    fold_pos: np.ndarray  # (N, S) int32 packed piece positions, FOLD_PAD pad
 
 
 def plan_tiles(
@@ -408,64 +402,63 @@ def plan_tiles(
     num_rows: int,
     vb: int,
     eb: int,
-    balance_rows: bool = False,
     split_threshold: int | None = None,
 ) -> TilePlan:
     """Decide one bucket's tile-layout shape from row counts alone.
 
-    Mirrors (and is consumed by) ``prepare_tiles``: the split decision, even
-    chunking, LPT placement, and the resulting (R, T) are pure functions of
-    the per-row counts, so a streaming builder can size its output buffers in
-    a counting pass and the edge-placement pass is guaranteed to fit."""
+    ``split_threshold`` is the bucket's block capacity, rounded up to whole
+    tiles: C edges. A natural block holding more than C gives up its excess,
+    taken from its heaviest rows first (ties: lower row), so it keeps exactly
+    C; the moved edges leave in pieces of at most C, which ``_pack_pieces``
+    puts into appended blocks of at most C edges. A row above C always
+    overflows, and no block holds more than C, so T <= C / Eb. A bucket
+    whose blocks all fit moves nothing: identity placement, no fold.
+    ``None`` is the identity placement whatever the loads."""
     assert num_rows % vb == 0, (num_rows, vb)
     r_base = num_rows // vb
     row_counts = np.asarray(row_counts, dtype=np.int64)
-    thr = max(int(split_threshold), 1) if split_threshold is not None else None
-    do_split = (
-        balance_rows and thr is not None and bool((row_counts > thr).any())
-    )
-    if do_split:
-        n_chunks = np.maximum(1, -(-row_counts // thr)).astype(np.int64)
-        num_split_rows = int((n_chunks > 1).sum())
-        num_virtual = int(n_chunks.sum())
-        r_blocks = max(r_base, -(-num_virtual // vb))
-        t_unsplit = max(1, -(-_lpt_max_load(row_counts, r_base, vb) // eb))
-        virt_base = np.cumsum(n_chunks) - n_chunks
-        virt_orig = np.repeat(np.arange(num_rows, dtype=np.int64), n_chunks)
-        # even-chunk sizes from (count, k) alone: chunk c of a row with count
-        # edges and k chunks holds ceil((c+1)*count/k) - ceil(c*count/k).
-        vidx = np.arange(num_virtual, dtype=np.int64) - virt_base[virt_orig]
-        cnt, k = row_counts[virt_orig], n_chunks[virt_orig]
-        virt_counts = (-(-((vidx + 1) * cnt) // k)) - (-(-(vidx * cnt) // k))
-        pos_v = _balance_row_blocks(virt_counts, r_blocks, vb)
-        row_orig = np.full(r_blocks * vb, -1, dtype=np.int32)
-        row_orig[pos_v] = virt_orig
-        loads = np.bincount(
-            pos_v // vb, weights=virt_counts.astype(np.float64),
-            minlength=r_blocks,
-        )
-        t_tiles = max(1, int(-(-int(loads.max()) // eb)))
+    loads = row_counts.reshape(r_base, vb).sum(axis=1)
+    t_identity = max(1, int(-(-int(loads.max()) // eb))) if loads.size else 1
+    cap = None
+    if split_threshold is not None:
+        cap = -(-max(int(split_threshold), 1) // eb) * eb
+    over = np.zeros(0, np.int64) if cap is None else np.flatnonzero(loads > cap)
+    if not over.size:
         return TilePlan(
-            r_blocks=r_blocks, t_tiles=t_tiles, t_tiles_unsplit=t_unsplit,
-            num_split_rows=num_split_rows, s_max=int(n_chunks.max()),
-            row_pos=None, row_orig=row_orig, n_chunks=n_chunks,
-            virt_base=virt_base, virt_pos=pos_v,
+            r_blocks=r_base, t_tiles=t_identity, t_tiles_unsplit=t_identity,
+            fold_rows=np.zeros(0, np.int32), fold_moved=np.zeros(0, np.int64),
+            fold_pos=np.zeros((0, 0), np.int32),
         )
-    if balance_rows and r_base > 1:
-        row_pos = _balance_row_blocks(row_counts, r_base, vb)
-        loads = np.bincount(
-            row_pos // vb, weights=row_counts.astype(np.float64),
-            minlength=r_base,
-        )
-        t_tiles = max(1, int(-(-int(loads.max()) // eb)))
-    else:
-        row_pos = None
-        loads = row_counts.reshape(r_base, vb).sum(axis=1)
-        t_tiles = max(1, int(-(-int(loads.max()) // eb))) if loads.size else 1
+    # each overfull block's rows, heaviest first (stable: lower row on ties);
+    # a row gives up what the block still holds above C after the heavier
+    # rows before it, at most its whole count
+    cnt = row_counts.reshape(r_base, vb)[over]
+    order = np.argsort(-cnt, axis=1, kind="stable")
+    cnt = np.take_along_axis(cnt, order, axis=1)
+    before = np.cumsum(cnt, axis=1) - cnt
+    take = np.clip((loads[over] - cap)[:, None] - before, 0, cnt)
+    moved_rows = (over[:, None] * vb + order)[take > 0]
+    moved = take[take > 0]
+    o = np.argsort(moved_rows, kind="stable")
+    moved_rows, moved = moved_rows[o], moved[o]
+    # k even pieces per moved row: piece c of a row that moves t edges holds
+    # the edges q with q * k // t == c, ceil((c+1)*t/k) - ceil(c*t/k) of them
+    k = -(-moved // cap)
+    owner = np.repeat(np.arange(moved_rows.shape[0]), k)
+    c = np.arange(owner.shape[0]) - np.repeat(np.cumsum(k) - k, k)
+    t, kk = moved[owner], k[owner]
+    sizes = (-(-((c + 1) * t) // kk)) - (-(-(c * t) // kk))
+    pos = _pack_pieces(sizes, cap, vb) + r_base * vb
+    fold_pos = np.full((moved_rows.shape[0], int(k.max())), FOLD_PAD, np.int32)
+    fold_pos[owner, c] = pos
+    r_blocks = int(pos.max()) // vb + 1
+    app_loads = np.bincount(pos // vb - r_base, weights=sizes.astype(np.float64),
+                            minlength=r_blocks - r_base)
+    top = max(int(np.minimum(loads, cap).max()), int(app_loads.max()))
     return TilePlan(
-        r_blocks=r_base, t_tiles=t_tiles, t_tiles_unsplit=t_tiles,
-        num_split_rows=0, s_max=1, row_pos=row_pos, row_orig=None,
-        n_chunks=None, virt_base=None, virt_pos=None,
+        r_blocks=r_blocks, t_tiles=max(1, -(-top // eb)),
+        t_tiles_unsplit=t_identity, fold_rows=moved_rows.astype(np.int32),
+        fold_moved=moved, fold_pos=fold_pos,
     )
 
 
@@ -497,68 +490,58 @@ def prepare_tiles(
     eb: int,
     weights: np.ndarray | None = None,
     *,
-    balance_rows: bool = False,
     split_threshold: int | None = None,
     plan: TilePlan | None = None,
 ) -> TileLayout:
     """Bin one (dst-sorted) edge bucket into (R, T, Eb) row-block tiles.
 
-    ``split_threshold`` (requires ``balance_rows``: virtual rows only help
-    when the LPT packer can spread them) caps the edge count of any single
-    kernel-output row: a natural row with more edges is split into
-    ``ceil(count / threshold)`` even chunks, each a virtual row the packer
-    places independently — R grows past ``num_rows / vb`` when the virtual
-    rows need the slots. The returned layout then carries ``row_orig`` and
-    the caller must apply the second-level combine (``combine_split_rows``).
-    When no row exceeds the threshold the output is byte-for-byte identical
-    to the unsplit layout.
+    ``split_threshold`` is the block capacity of ``plan_tiles``: every
+    natural row stays at its own output position, and a block above the
+    capacity moves its excess into appended blocks (R grows past ``num_rows
+    / vb``); the layout's ``fold_rows`` / ``fold_pos`` then tell the caller
+    what the second-level combine (``combine_split_rows``) folds. A moved
+    row keeps its first edges (in bucket order) in place. When no block is
+    above the capacity, or ``split_threshold`` is None, the output is the
+    identity placement.
 
     ``plan``: a ``TilePlan`` previously computed by ``plan_tiles`` for THIS
-    bucket's row counts under the same (vb, eb, balance_rows,
-    split_threshold) — skips the redundant re-plan (the LPT pass is the
-    expensive part at large vpc). The caller owns the consistency; the
-    t_tiles assertion below catches a mismatched plan.
+    bucket's row counts under the same (vb, eb, split_threshold) — skips the
+    re-plan. The caller owns the consistency; the t_tiles assertion below
+    catches a mismatched plan.
     """
     assert num_rows % vb == 0, (num_rows, vb)
-    src_gidx = np.asarray(src_gidx)
-    dst_lidx = np.asarray(dst_lidx)
-    valid = np.asarray(valid)
-
-    keep = valid
+    keep = np.asarray(valid)
     orig_idx = np.nonzero(keep)[0]
-    src_r = src_gidx[keep]
-    dst_r = dst_lidx[keep]
+    src_r = np.asarray(src_gidx)[keep]
+    dst_r = np.asarray(dst_lidx)[keep]
     w_r = weights[keep] if weights is not None else None
     row_counts = np.bincount(dst_r, minlength=num_rows)
     if plan is None:
         plan = plan_tiles(
             row_counts, num_rows=num_rows, vb=vb, eb=eb,
-            balance_rows=balance_rows, split_threshold=split_threshold,
+            split_threshold=split_threshold,
         )
     r_blocks = plan.r_blocks
-    if plan.row_orig is not None:
-        # level-1 layout over VIRTUAL rows: chunk c of natural row v holds
-        # the edges j with j * n_chunks[v] // count[v] == c (even split, so
-        # chunk sizes differ by at most 1 and never exceed the threshold).
+    pdst = dst_r.astype(np.int64)
+    if plan.fold_rows.size:
+        # edge j of moved row v (bucket order) stays at v while j < count -
+        # moved; the rest split into the plan's even chunks: the q-th moved
+        # edge of k pieces goes to piece q * k // moved
+        which = np.full(num_rows, -1, dtype=np.int64)
+        which[plan.fold_rows] = np.arange(plan.fold_rows.shape[0])
         row_starts = np.cumsum(row_counts) - row_counts
-        pos_in_row = np.arange(dst_r.shape[0], dtype=np.int64) - row_starts[dst_r]
-        chunk = pos_in_row * plan.n_chunks[dst_r] // np.maximum(row_counts[dst_r], 1)
-        vrow = plan.virt_base[dst_r] + chunk
-        pdst = plan.virt_pos[vrow]
+        n = which[dst_r]
+        q = (np.arange(dst_r.shape[0], dtype=np.int64) - row_starts[dst_r]
+             - (row_counts[dst_r] - np.where(n >= 0, plan.fold_moved[n], 0)))
+        mv = (n >= 0) & (q >= 0)
+        n, q = n[mv], q[mv]
+        k = (plan.fold_pos != FOLD_PAD).sum(axis=1)
+        pdst[mv] = plan.fold_pos[n, q * k[n] // plan.fold_moved[n]]
+        # regroup by block, keeping bucket order inside each block (stable)
         order = np.argsort(pdst // vb, kind="stable")
         src_r, pdst, orig_idx = src_r[order], pdst[order], orig_idx[order]
         if w_r is not None:
             w_r = w_r[order]
-    elif plan.row_pos is not None:
-        pdst = plan.row_pos[dst_r]
-        # packed positions are not sorted; regroup by block, keeping the
-        # original (dst-sorted) edge order inside each block (stable).
-        order = np.argsort(pdst // vb, kind="stable")
-        src_r, pdst, orig_idx = src_r[order], pdst[order], orig_idx[order]
-        if w_r is not None:
-            w_r = w_r[order]
-    else:
-        pdst = dst_r
     block = pdst // vb
     counts = np.bincount(block, minlength=r_blocks)
     t_tiles = max(1, int(-(-counts.max() // eb))) if counts.size else 1
@@ -581,80 +564,73 @@ def prepare_tiles(
             w_t[r].reshape(-1)[:n] = w_r[s:e]
     return TileLayout(
         src=src_t, dstb=dst_t, valid=val_t, weights=w_t, vb=vb,
-        num_rows=num_rows, gather_idx=gat_t, row_pos=plan.row_pos,
+        num_rows=num_rows, gather_idx=gat_t,
         tile_counts=(-(-counts // eb)).astype(np.int32),
-        row_orig=plan.row_orig, num_split_rows=plan.num_split_rows,
+        fold_rows=plan.fold_rows, fold_pos=plan.fold_pos,
         t_tiles_unsplit=plan.t_tiles_unsplit,
     )
 
 
-def split_map_from_row_orig(row_orig: np.ndarray, num_rows: int) -> np.ndarray:
-    """Invert a packed-position -> natural-row map into the gather form the
-    second-level combine consumes: ``(num_rows, S_max)`` packed positions per
-    natural row, padded with -1. Every natural row owns at least one virtual
-    row (empty rows get one whose kernel output is the reduce identity), so
-    column 0 is always a real position."""
-    row_orig = np.asarray(row_orig)
-    pos = np.nonzero(row_orig >= 0)[0]
-    orig = row_orig[pos].astype(np.int64)
-    order = np.argsort(orig, kind="stable")
-    orig_s, pos_s = orig[order], pos[order]
-    counts = np.bincount(orig_s, minlength=num_rows)
-    assert counts.min() >= 1, "every natural row must own >= 1 virtual row"
-    s_max = int(counts.max())
-    starts = np.cumsum(counts) - counts
-    rank = np.arange(pos_s.shape[0], dtype=np.int64) - starts[orig_s]
-    out = np.full((num_rows, s_max), -1, dtype=np.int32)
-    out[orig_s, rank] = pos_s
-    return out
+def stack_fold_arrays(
+    rows: list[np.ndarray], pos: list[np.ndarray], lead: tuple[int, ...]
+) -> tuple[np.ndarray | None, np.ndarray | None]:
+    """Stack per-bucket ``fold_rows`` (N_b,) / ``fold_pos`` (N_b, S_b) to
+    uniform (*lead, N) / (*lead, N, S) arrays, FOLD_PAD-padded, N and S the
+    maxima over the buckets (``lead`` orders them: (p, l) for a partition).
+    ``(None, None)`` when no bucket moved a row: no fold at all."""
+    n_max = max(r.shape[0] for r in rows)
+    if n_max == 0:
+        return None, None
+    s_max = max(p.shape[1] for p in pos)
+    fold_rows = np.full((len(rows), n_max), FOLD_PAD, np.int32)
+    fold_pos = np.full((len(rows), n_max, s_max), FOLD_PAD, np.int32)
+    for i, (r, p) in enumerate(zip(rows, pos)):
+        fold_rows[i, : r.shape[0]] = r
+        fold_pos[i, : p.shape[0], : p.shape[1]] = p
+    return fold_rows.reshape(*lead, n_max), fold_pos.reshape(*lead, n_max, s_max)
 
 
 @jax.named_scope("split_fold")
 def combine_split_rows(
-    reduced: jnp.ndarray,  # (..., P[, K]) level-1 kernel output, packed rows
-    split_map: jnp.ndarray,  # (..., num_rows, S) packed positions, -1 = pad
+    reduced: jnp.ndarray,  # (n, R*vb[, K]) level-1 kernel output, packed rows
+    fold_rows: jnp.ndarray | None,  # (n, N) moved natural rows, FOLD_PAD pad
+    fold_pos: jnp.ndarray | None,  # (n, N, S) their pieces' packed positions
     *,
+    num_rows: int,  # natural rows per channel (the natural blocks' rows)
     kind: str,  # 'min' | 'sum' | 'or' — the problem's reduce UDF
     identity: float,  # the SAME problem's identity (INF for min, 0 for sum/or)
 ) -> jnp.ndarray:
-    """Level-2 reduce: fold virtual-row partials into natural rows.
+    """Level-2 reduce: natural rows out of the packed kernel output.
 
-    Must use the problem's own reduce op and identity: padding entries
-    (-1) contribute ``identity``, so a min-problem sees INF (never 0) and a
-    sum-problem sees exactly 0.0 — a split row is neither double-counted nor
-    corrupted. Gather-based (static shapes, S_max is small), so min problems
-    stay bit-identical to the oracle: min over partial mins == total min.
-
-    Lane-batched problems (docs/tile_layout.md §8) pass ``reduced`` with a
-    trailing lane axis (..., P, K); the fold is over the packed-row axis and
-    broadcasts per lane — one gather serves all K columns.
+    The natural blocks are the output's first ``num_rows`` rows, taken as a
+    slice. Only the N moved rows gather anything: their S pieces' partials,
+    folded with the problem's own reduce op — a FOLD_PAD position reads the
+    problem's ``identity``, so a min problem never sees a stray 0 and a sum
+    problem never counts a partial twice — and combined into the row's
+    in-place partial; FOLD_PAD rows are dropped. Min problems stay
+    bit-identical to the oracle (min over partial mins is the total min).
+    Lane-batched problems (docs/tile_layout.md §8) carry a trailing lane
+    axis, which every index broadcasts over. Returns (n, num_rows[, K]).
     """
-    *lead, v, s = split_map.shape
-    idx = jnp.maximum(split_map, 0)
-    ident = jnp.asarray(identity, reduced.dtype)
-    if reduced.ndim == split_map.ndim:  # trailing lane axis (..., P, K)
-        k = reduced.shape[-1]
-        vals = jnp.take_along_axis(
-            reduced, idx.reshape(*lead, v * s, 1), axis=-2
-        )  # (..., v*s, K) — the size-1 index lane broadcasts over K
-        vals = vals.reshape(*lead, v, s, k)
-        vals = jnp.where(split_map[..., None] >= 0, vals, ident)
-        if kind == "min":
-            return jnp.min(vals, axis=-2)
-        if kind == "sum":
-            return jnp.sum(vals, axis=-2)
-        out = jnp.full(vals.shape[:-2] + (k,), ident, reduced.dtype)
-        for j in range(s):  # S_max is small & static: unrolled word-OR fold
-            out = out | vals[..., j, :]
+    out = reduced[:, :num_rows]
+    if fold_rows is None:
         return out
-    vals = jnp.take_along_axis(reduced, idx.reshape(*lead, v * s), axis=-1)
-    vals = jnp.where(split_map >= 0, vals.reshape(split_map.shape), ident)
-    if kind == "or":
-        out = jnp.full(split_map.shape[:-1], ident, reduced.dtype)
-        for j in range(s):
-            out = out | vals[..., j]
-        return out
-    return jnp.min(vals, axis=-1) if kind == "min" else jnp.sum(vals, axis=-1)
+    n, rows = fold_rows.shape
+    s = fold_pos.shape[-1]
+    ident = np.asarray(identity).astype(reduced.dtype).item()
+    lanes = reduced.shape[2:]
+    idx = fold_pos.reshape(n, rows * s, *([1] * len(lanes)))
+    vals = jnp.take_along_axis(reduced, idx, axis=1, mode="fill", fill_value=ident)
+    vals = vals.reshape(n, rows, s, *lanes)
+    at = out.at[jnp.arange(n)[:, None], fold_rows]
+    if kind == "min":
+        return at.min(jnp.min(vals, axis=2), mode="drop")
+    if kind == "sum":
+        return at.add(jnp.sum(vals, axis=2), mode="drop")
+    folded = at.get(mode="fill", fill_value=ident)
+    for j in range(s):  # S is small and static: an unrolled word-OR fold
+        folded = folded | vals[:, :, j]
+    return at.set(folded, mode="drop")
 
 
 def gather_reduce(
@@ -668,8 +644,8 @@ def gather_reduce(
     use_reference: bool = False,
 ) -> jnp.ndarray:
     """Run the accumulator over one (core, phase) bucket."""
-    # with hub-row splitting the kernel reduces PACKED (virtual) rows — may
-    # be more than the natural num_rows — and level 2 folds them back.
+    # the kernel reduces PACKED rows — natural blocks plus any appended
+    # overflow blocks — and level 2 folds the moved pieces back
     packed_rows = tiles.src.shape[0] * tiles.vb
     if use_reference:
         r_blocks = tiles.src.shape[0]
@@ -707,12 +683,12 @@ def gather_reduce(
             identity=identity,
             interpret=interpret,
         )
-    if tiles.row_orig is not None:  # level-2 reduce over virtual-row partials
-        sm = split_map_from_row_orig(tiles.row_orig, tiles.num_rows)
-        out = combine_split_rows(out, jnp.asarray(sm), kind=kind, identity=identity)
-    elif tiles.row_pos is not None:  # undo degree-aware row packing
-        out = jnp.take(out, jnp.asarray(tiles.row_pos), axis=0)
-    return out
+    rows, pos = stack_fold_arrays([tiles.fold_rows], [tiles.fold_pos], (1,))
+    return combine_split_rows(
+        out[None], None if rows is None else jnp.asarray(rows),
+        None if pos is None else jnp.asarray(pos),
+        num_rows=tiles.num_rows, kind=kind, identity=identity,
+    )[0]
 
 
 def segment_reduce_rows(

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 import repro.core.graph as G
 from repro.core.engine import EngineOptions, prepare_labels, run
-from repro.core.partition import PartitionConfig, partition_2d
+from repro.core.partition import PartitionConfig, _bucket_split_threshold, partition_2d
 from repro.core.problems import bfs, pagerank, sssp, wcc
 from repro.kernels.csr_gather_reduce.kernel import (
     gather_reduce_cores_pallas,
@@ -266,7 +266,7 @@ def test_engine_three_way(pname, force_bits, rng):
             jnp.asarray(pg.tile_counts[:, m]),
             jnp.asarray(pg.tile_word_hi[:, m]) if pg.tile_word_hi is not None else None,
             w_m,
-            num_rows=pg.vertices_per_core, vb=pg.tile_vb, src_bits=pg.src_bits,
+            num_rows=pg.packed_rows_per_core, vb=pg.tile_vb, src_bits=pg.src_bits,
             kind=prob.reduce_kind, edge_op=prob.edge_op,
             identity=prob.identity, interpret=True,
         )
@@ -275,8 +275,12 @@ def test_engine_three_way(pname, force_bits, rng):
                 pg.src_gidx[i, m], pg.dst_lidx[i, m], pg.valid[i, m],
                 num_rows=pg.vertices_per_core, vb=pg.tile_vb, eb=eb,
                 weights=pg.weights[i, m] if pg.weights is not None else None,
-                balance_rows=True,
+                split_threshold=_bucket_split_threshold(
+                    pg.config, int(pg.bucket_sizes[i, m]),
+                    pg.vertices_per_core // pg.tile_vb,
+                ),
             )
+            rows = tiles.src.shape[0] * pg.tile_vb
             uncompressed = gather_reduce_pallas(
                 gathered,
                 jnp.asarray(tiles.src), jnp.asarray(tiles.dstb),
@@ -284,12 +288,12 @@ def test_engine_three_way(pname, force_bits, rng):
                 jnp.asarray(tiles.weights)
                 if tiles.weights is not None and prob.edge_op == "add"
                 else None,
-                num_rows=pg.vertices_per_core, vb=pg.tile_vb,
+                num_rows=rows, vb=pg.tile_vb,
                 kind=prob.reduce_kind, edge_op=prob.edge_op,
                 identity=prob.identity, interpret=True,
             )
             np.testing.assert_array_equal(
-                np.asarray(compressed[i]), np.asarray(uncompressed)
+                np.asarray(compressed[i, :rows]), np.asarray(uncompressed)
             )
 
 

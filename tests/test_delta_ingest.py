@@ -6,7 +6,7 @@ The equivalence argument under test: the cold path sorts the whole edge list
 with one stable argsort on (bucket, lidx); a dirty bucket's merged slice —
 old dst-sorted slice ++ delta edges in insertion order, stably re-sorted by
 lidx — reproduces exactly that tie order, and per-bucket layout decisions
-(LPT packing, 'auto' split threshold, E_pad rounding) are local, so clean
+(row placement, 'auto' capacity, E_pad rounding) are local, so clean
 buckets never move. Composition across flushes means N incremental flushes
 == one cold repartition of the final graph.
 """
@@ -113,12 +113,12 @@ def test_stride_permutation_flush():
 
 
 def test_hub_split_bucket_flush():
-    """Deltas landing on an already-split hub bucket re-run the two-level
-    split with the recomputed 'auto' threshold — still bit-identical."""
+    """Deltas landing on a hub bucket that already moved rows re-run the
+    placement with the recomputed 'auto' capacity — still bit-identical."""
     g = skewed_graph(256, kind="star", hub_in_degree=700, avg_degree=2, seed=7)
     cfg = PartitionConfig(p=2, l=2, lane=8, tile_vb=32, tile_eb=32)
     pg = partition_2d(g, cfg)
-    assert pg.split_rows > 0, "precondition: the hub must be split"
+    assert pg.split_rows > 0, "precondition: the hub's block must overflow"
     hub = int(np.argmax(np.bincount(g.dst, minlength=g.num_vertices)))
     rng = np.random.default_rng(8)
     src = rng.integers(0, g.num_vertices, 64)
@@ -129,18 +129,19 @@ def test_hub_split_bucket_flush():
 
 
 def test_pos_to_split_mode_transition():
-    """A delta that pushes one row over the split threshold flips the layout
-    from pos-mode (tile_row_pos) to split-mode — clean buckets' row maps are
-    derived mechanically, and the result still matches cold."""
-    g = G.symmetrize(G.rmat(7, 3, seed=4))
+    """A delta that pushes one row block over its capacity moves rows for
+    the first time: the partition gains fold arrays (None before), clean
+    buckets keep theirs empty, and the result still matches cold."""
+    g = G.symmetrize(G.erdos_renyi(128, 300, seed=4))
     cfg = PartitionConfig(p=2, l=2, lane=8, tile_vb=32, tile_eb=32)
     pg = partition_2d(g, cfg)
-    assert pg.tile_split_map is None, "precondition: no split before the delta"
+    assert pg.tile_fold_rows is None, "precondition: nothing moved before the delta"
     rng = np.random.default_rng(9)
     src = rng.integers(0, g.num_vertices, 600)
     dst = np.zeros(600, dtype=np.int64)  # one monster row
     new_pg, report = apply_edge_deltas(pg, src, dst)
-    assert report.mode_changed and new_pg.tile_split_map is not None
+    assert new_pg.tile_fold_rows is not None and new_pg.split_rows > 0
+    assert report.buckets_retiled < report.total_buckets
     assert_partitions_identical(new_pg, partition_2d(_grown(g, src, dst), cfg))
 
 

@@ -61,13 +61,13 @@ def test_fused_matches_xla(pname, immediate, p, l, rng):
 
 @pytest.mark.parametrize("stride", [None, 7])
 def test_fused_matches_xla_with_stride_and_packing_off(stride, rng):
-    """Degree-aware packing and stride mapping are layout choices — results
-    must not change."""
+    """Moving overflow out of a block and stride mapping are layout choices
+    — results must not change."""
     g = G.symmetrize(G.rmat(8, 6, seed=21))
-    for packing in (True, False):
+    for threshold in ("auto", None):
         pg = partition_2d(
             g,
-            PartitionConfig(p=2, l=2, lane=4, stride=stride, degree_aware_tiles=packing),
+            PartitionConfig(p=2, l=2, lane=4, stride=stride, split_threshold=threshold),
         )
         a = run(bfs(0), g, pg, EngineOptions(backend="pallas"))
         b = run(bfs(0), g, pg, EngineOptions(backend="xla"))
@@ -136,24 +136,41 @@ def test_fused_kernel_runs_all_cores_in_one_launch():
 
 
 def test_degree_aware_packing_reduces_tile_padding():
-    """LPT row packing must never do worse than natural row order, and on a
-    skew-clustered graph (R-MAT low-id hubs) it must do strictly better."""
+    """Moving an overfull block's excess must never do worse than the
+    identity placement, and on a skew-clustered graph (R-MAT low-id hubs)
+    it must do strictly better."""
     g = G.symmetrize(G.rmat(12, 8, seed=2))
     cfg = dict(p=4, l=2, lane=4, tile_vb=32)
-    packed = partition_2d(g, PartitionConfig(**cfg, degree_aware_tiles=True))
-    plain = partition_2d(g, PartitionConfig(**cfg, degree_aware_tiles=False))
+    packed = partition_2d(g, PartitionConfig(**cfg))
+    plain = partition_2d(g, PartitionConfig(**cfg, split_threshold=None))
     assert packed.tile_word.shape[3] < plain.tile_word.shape[3]  # T shrinks
     assert packed.tile_padding_ratio < plain.tile_padding_ratio
 
 
 def test_row_pos_is_a_permutation():
+    """Natural rows stay in place: the first Vl kernel-output rows of every
+    bucket are its natural rows, in order, and each moved row is named once
+    in its bucket's fold."""
+    from repro.kernels.csr_gather_reduce.ops import FOLD_PAD
+
     g = G.symmetrize(G.rmat(9, 6, seed=7))
     pg = partition_2d(g, PartitionConfig(p=2, l=2, lane=4, tile_vb=16))
-    assert pg.tile_row_pos is not None
-    vpc = pg.vertices_per_core
+    assert pg.tile_fold_rows is not None
+    vpc, vb = pg.vertices_per_core, pg.tile_vb
+    r0 = vpc // vb
+    word = pg.tile_word.reshape(*pg.tile_word.shape[:4], -1)
     for i in range(pg.p):
         for m in range(pg.l):
-            assert sorted(pg.tile_row_pos[i, m].tolist()) == list(range(vpc))
+            n = int(pg.bucket_sizes[i, m])
+            w = word[i, m, :r0]
+            valid = w < 0
+            row = ((w >> 16) & 0x7FFF) + np.arange(r0)[:, None, None] * vb
+            kept = np.bincount(row[valid], minlength=vpc)
+            full = np.bincount(pg.dst_lidx[i, m, :n], minlength=vpc)
+            moved = np.flatnonzero(kept < full)
+            assert (kept <= full).all()
+            rows = pg.tile_fold_rows[i, m]
+            np.testing.assert_array_equal(rows[rows != FOLD_PAD], moved)
 
 
 def test_sssp_unit_weights_without_weight_array(rng):

@@ -1,29 +1,45 @@
-"""Hub-row splitting (two-level reduce) suite — ISSUE 3.
+"""Two-level reduce suite: natural rows stay in place, an overfull block's
+excess moves to appended blocks, and the fold gathers only the moved rows.
 
-Covers the split layout invariants, the LPT/prepare_tiles edge cases splitting
-exposes (a row holding most of the bucket, multi-way splits bigger than the
-unsplit T_max * Eb, empty buckets), equivalence of split-Pallas vs
-unsplit-Pallas vs the XLA oracle across BFS/WCC/SSSP/PR, the identity-element
-regression (a min-problem's split combine must fold with the problem's
-identity — INF — and never inject the sum identity 0), and the
-disable-switch (``split_threshold=None`` preserves the pre-split layout
-byte for byte).
+Covers the placement invariants (every natural row's in-place piece at its
+own output position, no block above the capacity C so T <= C / Eb, fold
+arrays sized by the moved rows and not by V) on a hub graph and on an R-MAT
+whose hubs sit at low ids; the prepare_tiles edge cases (a row holding most
+of the bucket, a many-way split of one row, empty buckets); a jaxpr check
+that a pull phase gathers and scatters nothing larger than N x S;
+equivalence of moved-Pallas vs identity-Pallas vs the XLA oracle across
+BFS/WCC/SSSP/PR; the identity-element regression (a min problem's fold must
+read INF for padding, never the sum identity 0); and ``split_threshold=None``
+(the identity placement, no fold).
 """
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import repro.core.graph as G
-from repro.core.engine import EngineOptions, run
-from repro.core.partition import PartitionConfig, partition_2d
+from repro.core.engine import (
+    EngineOptions,
+    _edge_constants,
+    _phase_reduce_pallas,
+    prepare_labels,
+    run,
+)
+from repro.core.partition import (
+    PartitionConfig,
+    _bucket_split_threshold,
+    apply_permutation,
+    partition_2d,
+)
 from repro.core.problems import INF_U32, bfs, pagerank, sssp, wcc
 from repro.data.synthetic import skewed_graph
 from repro.kernels.csr_gather_reduce.ops import (
+    FOLD_PAD,
     combine_split_rows,
     gather_reduce,
     prepare_tiles,
-    split_map_from_row_orig,
+    stack_packed_tiles,
 )
 
 PROBLEMS = ["bfs", "wcc", "sssp", "pagerank"]
@@ -64,9 +80,36 @@ def _problem(pname, g, rng):
 # ---------------------------------------------------------------------------
 
 
+def _check_placement(t, dst, cap):
+    """The placement contract for one bucket laid out by prepare_tiles from
+    the dst-sorted rows ``dst``: in-place pieces at their own position,
+    moved pieces only at their row's fold positions, every block at most
+    ``cap`` edges, and fold arrays sized by the moved rows."""
+    r_base = t.num_rows // t.vb
+    pos = t.dstb + np.arange(t.src.shape[0])[:, None, None] * t.vb
+    row = dst[t.gather_idx]  # natural row of every slot
+    natural = pos < t.num_rows
+    assert (pos[natural & t.valid] == row[natural & t.valid]).all()
+    moved = t.valid & ~natural
+    fold = dict(zip(t.fold_rows.tolist(), t.fold_pos.tolist()))
+    for p_, r_ in zip(pos[moved].tolist(), row[moved].tolist()):
+        assert p_ in fold[r_]
+    loads = t.valid.sum(axis=(1, 2))
+    assert loads.max() <= cap and t.src.shape[1] <= cap // t.src.shape[2]
+    # every edge once; the fold names exactly the rows with a moved piece
+    np.testing.assert_array_equal(
+        np.bincount(row[t.valid], minlength=t.num_rows),
+        np.bincount(dst, minlength=t.num_rows),
+    )
+    np.testing.assert_array_equal(t.fold_rows, np.unique(row[moved]))
+    pieces = (t.fold_pos != FOLD_PAD).sum(axis=1)
+    assert t.fold_pos.shape == (t.fold_rows.shape[0], pieces.max(initial=0))
+    assert t.src.shape[0] == max(r_base, int(pos[moved].max(initial=-1)) // t.vb + 1)
+
+
 def test_split_layout_invariants():
-    """Virtual rows partition every natural row's edges; row_orig covers all
-    natural rows; chunk sizes respect the threshold."""
+    """A hub row and a crowded block: both give up their excess, the hub
+    in several pieces; the fold covers every moved row and nothing else."""
     rng = np.random.default_rng(0)
     v, e, thr = 64, 900, 40
     dst = np.sort(rng.integers(0, v, e)).astype(np.int32)
@@ -74,31 +117,85 @@ def test_split_layout_invariants():
     dst = np.sort(dst)
     src = rng.integers(0, 128, e).astype(np.int32)
     t = prepare_tiles(src, dst, np.ones(e, bool), num_rows=v, vb=8, eb=16,
-                      balance_rows=True, split_threshold=thr)
-    assert t.row_orig is not None and t.row_pos is None
-    assert t.num_split_rows >= 1
-    packed_rows = t.src.shape[0] * t.vb
-    assert t.row_orig.shape == (packed_rows,)
-    # every natural row owns >= 1 virtual row; hub owns ceil(450/40) = 12
-    owned = np.bincount(t.row_orig[t.row_orig >= 0], minlength=v)
-    assert owned.min() >= 1
-    assert owned[5] == -(-int((dst == 5).sum()) // thr)
-    # per-virtual-row edge counts never exceed the threshold
-    block_rows = t.dstb + (np.arange(t.src.shape[0])[:, None, None] * t.vb)
-    per_pos = np.bincount(block_rows[t.valid], minlength=packed_rows)
-    assert per_pos.max() <= thr
-    # edges per natural row are conserved through the split
-    orig_per_pos = t.row_orig.copy()
-    recon = np.zeros(v, np.int64)
-    np.add.at(recon, orig_per_pos[orig_per_pos >= 0], per_pos[orig_per_pos >= 0])
-    np.testing.assert_array_equal(recon, np.bincount(dst, minlength=v))
-    # split_map inverts row_orig
-    sm = split_map_from_row_orig(t.row_orig, v)
-    assert sm.shape[0] == v and (sm[:, 0] >= 0).all()
-    for row in range(v):
-        np.testing.assert_array_equal(
-            np.sort(sm[row][sm[row] >= 0]), np.nonzero(t.row_orig == row)[0]
-        )
+                      split_threshold=thr)
+    cap = 48  # thr rounded up to whole tiles
+    _check_placement(t, dst, cap)
+    assert 5 in t.fold_rows.tolist() and t.num_split_rows >= 1
+    hub = t.fold_rows.tolist().index(5)
+    # the hub's block keeps cap edges, the hub's own share of them in place
+    kept = int(((t.dstb[0] == 5) & t.valid[0]).sum())
+    moved = int((dst == 5).sum()) - kept
+    assert int((t.fold_pos[hub] != FOLD_PAD).sum()) == -(-moved // cap)
+
+
+@pytest.mark.parametrize("graph", ["hub", "rmat_low_id_hubs"])
+def test_placement_invariants(graph, rng):
+    """Every bucket of a hub graph and of an R-MAT whose hubs sit at low ids
+    (not relabelled): in-place pieces at their own rows, every block at most
+    C edges (so T <= C / Eb), and fold arrays sized by the moved rows."""
+    if graph == "hub":
+        g = _hub_graph(rng)
+        cfg = PartitionConfig(p=2, l=2, lane=8, tile_vb=32, tile_eb=32)
+    else:
+        g = G.symmetrize(G.rmat(11, 8, seed=3))
+        cfg = PartitionConfig(p=2, l=2, lane=8, tile_vb=64, tile_eb=32)
+    pg = partition_2d(g, cfg)
+    vpc, vb = pg.vertices_per_core, pg.tile_vb
+    moved = []
+    for i in range(pg.p):
+        for m in range(pg.l):
+            n = int(pg.bucket_sizes[i, m])
+            dst = pg.dst_lidx[i, m, :n]
+            thr = _bucket_split_threshold(cfg, n, vpc // vb)
+            t = prepare_tiles(pg.src_gidx[i, m], pg.dst_lidx[i, m],
+                              pg.valid[i, m], num_rows=vpc, vb=vb, eb=32,
+                              split_threshold=thr)
+            _check_placement(t, dst, -(-thr // 32) * 32)
+            moved.append(t.fold_rows.shape[0])
+    assert pg.split_rows == sum(moved) > 0
+    n_max = max(moved)
+    assert pg.tile_fold_rows.shape == (pg.p, pg.l, n_max)
+    assert pg.tile_fold_pos.shape[:3] == (pg.p, pg.l, n_max)
+    assert n_max < vpc // 4  # sized by the moved rows, not by V
+    assert pg.tile_word.shape[3] < partition_2d(
+        g, PartitionConfig(**{**cfg.__dict__, "split_threshold": None})
+    ).tile_word.shape[3]
+
+
+def test_pull_phase_gathers_only_moved_rows():
+    """On a Graph500-like layout (relabelled Kronecker graph, scale 12,
+    l = 16) the traced pull phase gathers and scatters nothing larger than
+    the fold's N x S: the natural rows are a slice, not a gather."""
+    g = G.symmetrize(G.rmat(12, 16, seed=5))
+    perm = np.random.default_rng(6).permutation(g.num_vertices)
+    g = apply_permutation(g, perm)
+    pg = partition_2d(g, PartitionConfig(p=1, l=16, lane=8, tile_vb=64,
+                                         tile_eb=128, build_push=False))
+    assert pg.tile_fold_rows is not None
+    n, s = pg.tile_fold_pos.shape[2:]
+    assert n * s < pg.vertices_per_core // 8
+    for prob in (bfs(0), pagerank()):
+        opts = EngineOptions()
+        consts = _edge_constants(prob, pg, opts)
+        labels = prepare_labels(prob, g, pg)
+        jaxpr = jax.make_jaxpr(
+            lambda lab, c: _phase_reduce_pallas(prob, pg, c, lab, 3, opts)
+        )(labels, consts)
+        sizes = []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                name = eqn.primitive.name
+                if name == "gather" or name.startswith("scatter"):
+                    # index tuples: the indices' last axis is the tuple
+                    sizes.append(int(np.prod(eqn.invars[1].aval.shape[:-1])))
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub.jaxpr if hasattr(sub, "jaxpr") else sub)
+
+        walk(jaxpr.jaxpr)
+        # the fold is there (a gather of N x S pieces, a scatter of N rows)
+        # and nothing indexes more than N x S elements
+        assert sizes and max(sizes) == n * s, sizes
 
 
 def test_single_row_majority_of_edges():
@@ -112,10 +209,9 @@ def test_single_row_majority_of_edges():
     ]).astype(np.int32))
     e = dst.shape[0]
     src = rng.integers(0, 64, e).astype(np.int32)
-    un = prepare_tiles(src, dst, np.ones(e, bool), num_rows=v, vb=vb, eb=eb,
-                       balance_rows=True)
+    un = prepare_tiles(src, dst, np.ones(e, bool), num_rows=v, vb=vb, eb=eb)
     sp = prepare_tiles(src, dst, np.ones(e, bool), num_rows=v, vb=vb, eb=eb,
-                       balance_rows=True, split_threshold=64)
+                       split_threshold=64)
     assert sp.src.shape[1] < un.src.shape[1]  # T shrinks
     assert sp.t_tiles_unsplit == un.src.shape[1]
     payload = jnp.asarray(rng.random(64).astype(np.float32))
@@ -130,7 +226,7 @@ def test_single_row_majority_of_edges():
 
 def test_multiway_split_row_bigger_than_tmax_eb():
     """A hub bigger than the whole rest of the bucket times T_max*Eb forces a
-    many-way split; R must grow past num_rows/vb to hold the virtual rows."""
+    many-way split; R must grow past num_rows/vb to hold the pieces."""
     rng = np.random.default_rng(2)
     v, vb, eb = 16, 8, 8
     hub_e = 1000
@@ -139,9 +235,13 @@ def test_multiway_split_row_bigger_than_tmax_eb():
     ]).astype(np.int32))
     src = rng.integers(0, 32, dst.shape[0]).astype(np.int32)
     sp = prepare_tiles(src, dst, np.ones(dst.shape[0], bool), num_rows=v,
-                       vb=vb, eb=eb, balance_rows=True, split_threshold=eb)
-    n_chunks = -(-int((dst == 2).sum()) // eb)  # ~125 virtual rows, one row
-    assert (sp.row_orig == 2).sum() == n_chunks
+                       vb=vb, eb=eb, split_threshold=eb)
+    _check_placement(sp, dst, eb)
+    # block 0 keeps eb edges: the hub moves what the block holds above that
+    kept = int(((sp.dstb[0] == 2) & sp.valid[0]).sum())
+    n_chunks = -(-(int((dst == 2).sum()) - kept) // eb)  # ~125 pieces
+    hub = sp.fold_rows.tolist().index(2)
+    assert (sp.fold_pos[hub] != FOLD_PAD).sum() == n_chunks
     assert sp.src.shape[0] > v // vb  # R grew
     payload = jnp.asarray(rng.random(32).astype(np.float32))
     out = gather_reduce(payload, sp, kind="min",
@@ -154,12 +254,12 @@ def test_multiway_split_row_bigger_than_tmax_eb():
 
 
 def test_empty_bucket_and_empty_blocks():
-    """Empty (core, phase) buckets and rows with zero edges survive the split
-    path: counts 0, one virtual row per natural row, identity outputs."""
+    """Empty (core, phase) buckets and rows with zero edges survive the
+    placement: counts 0, nothing moved, identity outputs."""
     t = prepare_tiles(np.zeros(0, np.int32), np.zeros(0, np.int32),
                       np.zeros(0, bool), num_rows=16, vb=4, eb=4,
-                      balance_rows=True, split_threshold=2)
-    assert t.row_orig is None and t.num_split_rows == 0  # nothing to split
+                      split_threshold=2)
+    assert t.fold_rows.size == 0 and t.num_split_rows == 0  # nothing moved
     out = gather_reduce(jnp.ones(8, jnp.float32), t, kind="min", identity=7.0)
     np.testing.assert_array_equal(np.asarray(out), np.full(16, 7.0, np.float32))
 
@@ -176,18 +276,16 @@ def test_engine_split_three_way(pname, rng):
     cfg = dict(p=2, l=2, lane=8, tile_vb=32, tile_eb=32)
     pg_split = partition_2d(g, PartitionConfig(**cfg))
     pg_none = partition_2d(g, PartitionConfig(**cfg, split_threshold=None))
-    assert pg_split.split_rows > 0, "hub graph must trigger splitting"
-    assert pg_split.tile_split_map is not None
+    assert pg_split.split_rows > 0, "hub graph must move rows"
+    assert pg_split.tile_fold_rows is not None and pg_none.tile_fold_rows is None
     assert pg_split.tile_word.shape[3] < pg_none.tile_word.shape[3]
-    # tile_split_map is exactly the gather form of tile_row_orig — the engine
-    # reads the former, tests/debugging read the latter; they must not drift
-    vl, s_max = pg_split.tile_split_map.shape[2:]
+    # the fold names each moved row once per bucket, in ascending order
     for i in range(pg_split.p):
         for m in range(pg_split.l):
-            sm = split_map_from_row_orig(pg_split.tile_row_orig[i, m], vl)
-            expect = np.full((vl, s_max), -1, np.int32)
-            expect[:, : sm.shape[1]] = sm
-            np.testing.assert_array_equal(pg_split.tile_split_map[i, m], expect)
+            rows = pg_split.tile_fold_rows[i, m]
+            real = rows[rows != FOLD_PAD]
+            assert (np.diff(real) > 0).all()
+            assert (pg_split.tile_fold_pos[i, m][rows == FOLD_PAD] == FOLD_PAD).all()
 
     res_s = run(prob, g, pg_split, EngineOptions(backend="pallas"))
     res_u = run(prob, g, pg_none, EngineOptions(backend="pallas"))
@@ -240,20 +338,37 @@ def test_engine_split_32bit_regime(rng):
 
 
 def test_combine_uses_reduce_identity_not_zero():
-    """Padded split_map entries contribute the problem's identity: a wrong
-    0-identity in a min combine would zero every label; a wrong INF in a sum
-    combine would blow it up; reusing a real position would double-count."""
-    reduced = jnp.asarray(np.array([5.0, 7.0, 11.0, 2.0], np.float32))
-    # row 0 owns positions {0, 2}; row 1 owns {3} with one padded entry
-    sm = jnp.asarray(np.array([[0, 2], [3, -1]], np.int32))
-    out_min = combine_split_rows(reduced, sm, kind="min", identity=float(np.inf))
-    np.testing.assert_array_equal(np.asarray(out_min), [5.0, 2.0])
-    out_sum = combine_split_rows(reduced, sm, kind="sum", identity=0.0)
-    np.testing.assert_array_equal(np.asarray(out_sum), [16.0, 2.0])
+    """FOLD_PAD positions contribute the problem's identity: a wrong
+    0-identity in a min fold would zero the row; a wrong INF in a sum fold
+    would blow it up; reusing a real position would double-count. FOLD_PAD
+    rows are dropped, and rows that did not move read their own position."""
+    # natural rows 0..2; row 0 moved one piece (pos 3), row 2 two (4, 5)
+    rows = jnp.asarray(np.array([[0, 2, FOLD_PAD]], np.int32))
+    pos = jnp.asarray(np.array(
+        [[[3, FOLD_PAD], [4, 5], [FOLD_PAD, FOLD_PAD]]], np.int32))
+    reduced = jnp.asarray(np.array([[5.0, 7.0, 11.0, 2.0, 13.0, 3.0]], np.float32))
+    kw = dict(num_rows=3)
+    out_min = combine_split_rows(reduced, rows, pos, kind="min",
+                                 identity=float(np.inf), **kw)
+    np.testing.assert_array_equal(np.asarray(out_min), [[2.0, 7.0, 3.0]])
+    out_sum = combine_split_rows(reduced, rows, pos, kind="sum", identity=0.0, **kw)
+    np.testing.assert_array_equal(np.asarray(out_sum), [[7.0, 7.0, 27.0]])
     # uint32 min path (BFS/WCC labels): identity INF_U32 survives the cast
-    red_u = jnp.asarray(np.array([3, INF_U32, 9, 1], np.uint32))
-    out_u = combine_split_rows(red_u, sm, kind="min", identity=float(INF_U32))
-    np.testing.assert_array_equal(np.asarray(out_u), [3, 1])
+    red_u = jnp.asarray(np.array([[3, INF_U32, 9, 1, INF_U32, 8]], np.uint32))
+    out_u = combine_split_rows(red_u, rows, pos, kind="min",
+                               identity=float(INF_U32), **kw)
+    np.testing.assert_array_equal(np.asarray(out_u), [[1, INF_U32, 8]])
+    # word OR with a lane axis: identity 0, every lane folded alike
+    red_w = jnp.asarray(np.array([[[1, 16], [2, 0], [4, 0], [8, 0], [0, 32],
+                                   [64, 0]]], np.uint32))
+    out_w = combine_split_rows(red_w, rows, pos, kind="or", identity=0.0, **kw)
+    np.testing.assert_array_equal(np.asarray(out_w), [[[9, 16], [2, 0], [68, 32]]])
+    # nothing moved: the fold is the natural blocks' slice
+    np.testing.assert_array_equal(
+        np.asarray(combine_split_rows(reduced, None, None, kind="min",
+                                      identity=float(np.inf), **kw)),
+        [[5.0, 7.0, 11.0]],
+    )
 
 
 def test_bfs_unreached_hub_row_stays_inf(rng):
@@ -317,32 +432,32 @@ def test_star_t_max_halved_and_metrics():
 
 
 def test_split_threshold_none_preserves_old_layout():
-    """Disable switch: split_threshold=None must reproduce the pre-split
-    layout byte for byte (row_pos permutation, no split fields) even on a
-    graph whose default partition splits."""
+    """``split_threshold=None`` is the identity placement even on a graph
+    whose default partition moves rows: no fold arrays, R = Vl / vb, every
+    block holds exactly its own rows' edges in bucket order — byte for byte
+    what a manual identity prepare_tiles stack gives."""
     g = skewed_graph(512, kind="star", hub_in_degree=2000, avg_degree=2, seed=3)
     cfg = dict(p=2, l=2, lane=8, tile_vb=32, tile_eb=32)
     pg_auto = partition_2d(g, PartitionConfig(**cfg))
     pg_none = partition_2d(g, PartitionConfig(**cfg, split_threshold=None))
     assert pg_auto.split_rows > 0
-    assert pg_none.tile_row_orig is None and pg_none.tile_split_map is None
+    assert pg_none.tile_fold_rows is None and pg_none.tile_fold_pos is None
     assert pg_none.split_rows == 0
-    assert pg_none.tile_row_pos is not None
-    vpc = pg_none.vertices_per_core
-    for i in range(pg_none.p):
-        for m in range(pg_none.l):
-            assert sorted(pg_none.tile_row_pos[i, m].tolist()) == list(range(vpc))
-    # and byte-for-byte: None matches a manual unsplit prepare_tiles stack
-    from repro.kernels.csr_gather_reduce.ops import stack_packed_tiles
-
+    vpc, vb = pg_none.vertices_per_core, pg_none.tile_vb
+    assert pg_none.tile_word.shape[2] == vpc // vb
     layouts = [
         prepare_tiles(
             pg_none.src_gidx[i, m], pg_none.dst_lidx[i, m], pg_none.valid[i, m],
-            num_rows=vpc, vb=pg_none.tile_vb, eb=32, balance_rows=True,
+            num_rows=vpc, vb=vb, eb=32,
         )
         for i in range(pg_none.p)
         for m in range(pg_none.l)
     ]
+    for t in layouts:  # natural rows, in place
+        row = t.dstb + np.arange(t.src.shape[0])[:, None, None] * vb
+        n = int(t.valid.sum())
+        order = np.argsort(t.gather_idx[t.valid], kind="stable")
+        assert (np.diff(row[t.valid][order]) >= 0).all() and n == order.size
     word, _, counts, _ = stack_packed_tiles(layouts, src_bits=pg_none.src_bits)
     np.testing.assert_array_equal(
         pg_none.tile_word, word.reshape(pg_none.tile_word.shape)
@@ -353,16 +468,17 @@ def test_split_threshold_none_preserves_old_layout():
 
 
 def test_auto_threshold_no_hub_is_identical_to_disabled():
-    """'auto' on a hub-free graph never splits, so the layout equals the
-    disabled one exactly — the default is safe for every existing graph."""
-    g = G.symmetrize(G.rmat(8, 6, seed=13))
-    cfg = dict(p=2, l=2, lane=4)
+    """'auto' on a graph whose blocks all fit their capacity moves nothing,
+    so the layout equals the identity placement exactly and the fold is the
+    slice alone — a balanced graph pays nothing for the default."""
+    g = G.symmetrize(G.erdos_renyi(1024, 1000, seed=13))
+    cfg = dict(p=2, l=2, lane=4, tile_vb=64)
     pg_auto = partition_2d(g, PartitionConfig(**cfg))
     pg_none = partition_2d(g, PartitionConfig(**cfg, split_threshold=None))
-    assert pg_auto.split_rows == 0 and pg_auto.tile_row_orig is None
+    assert pg_auto.split_rows == 0 and pg_auto.tile_fold_rows is None
     np.testing.assert_array_equal(pg_auto.tile_word, pg_none.tile_word)
     np.testing.assert_array_equal(pg_auto.tile_counts, pg_none.tile_counts)
-    np.testing.assert_array_equal(pg_auto.tile_row_pos, pg_none.tile_row_pos)
+    assert pg_auto.channel_arrays()["fold_rows"] is None
 
 
 def test_skewed_graph_generator_deterministic():

@@ -2,9 +2,9 @@
 
 The streaming contract (docs/tile_layout.md §11): ``partition_2d_streaming``
 over any chunking of the same edge sequence is BIT-IDENTICAL to
-``partition_2d`` over the materialized array — every packed/flat field, both
-row-map modes (LPT ``row_pos`` and hub-split ``row_orig``/``split_map``),
-both src-bit regimes, weighted or not, memmap-backed or RAM. Plus: the
+``partition_2d`` over the materialized array — every packed/flat field, with
+and without moved rows (the ``fold_rows``/``fold_pos`` arrays), both src-bit
+regimes, weighted or not, memmap-backed or RAM. Plus: the
 two-pass chunk protocol (re-iterable required, one-shot generators rejected,
 deterministic replay verified), the seeded graph500-style RMAT stream
 (``repro.data.rmat``), the ``choose_src_bits`` 16→32 boundary at exactly
@@ -35,7 +35,7 @@ from repro.kernels.csr_gather_reduce.ops import (
 IDENTITY_FIELDS = (
     "src_gidx", "dst_lidx", "valid", "weights", "bucket_sizes",
     "tile_word", "tile_word_hi", "tile_counts", "tile_weights",
-    "tile_coverage", "tile_row_pos", "tile_row_orig", "tile_split_map",
+    "tile_coverage", "tile_fold_rows", "tile_fold_pos",
     "push_word", "push_word_hi", "push_counts", "push_weights",
     "push_coverage",
 )
@@ -96,22 +96,22 @@ class TestChooseSrcBits:
 
 class TestStreamingIdentity:
     def test_split_map_mode(self):
-        # forced low threshold → virtual rows → row_orig/split_map layout
+        # forced low capacity → rows move to appended blocks → fold arrays
         g = _hub_graph()
         cfg = PartitionConfig(p=2, l=2, tile_vb=16, tile_eb=16,
                               split_threshold=16)
         pg = partition_2d(g, cfg)
-        assert pg.tile_row_orig is not None and pg.split_rows > 0
+        assert pg.tile_fold_rows is not None and pg.split_rows > 0
         ps = partition_2d_streaming(coo_edge_chunks(g, 97), g.num_vertices, cfg)
         assert_identical(ps, pg)
 
     def test_row_pos_mode(self):
-        # splitting off, LPT balancing on → row_pos permutation layout
+        # no capacity → identity placement, no fold arrays
         g = _hub_graph()
         cfg = PartitionConfig(p=2, l=2, tile_vb=16, tile_eb=16,
                               split_threshold=None)
         pg = partition_2d(g, cfg)
-        assert pg.tile_row_orig is None and pg.tile_row_pos is not None
+        assert pg.tile_fold_rows is None and pg.split_rows == 0
         ps = partition_2d_streaming(coo_edge_chunks(g, 97), g.num_vertices, cfg)
         assert_identical(ps, pg)
 

@@ -147,9 +147,13 @@ def test_pull_tiles_run_matches_the_stepped_schedule(request, graph, make, direc
     g, pg = request.getfixturevalue(graph)
     prob, opts = make(), EngineOptions(direction=direction)
     trace = run_frontier_trace(prob, g, pg, opts)
+    telemetry.clear()
     res = run(prob, g, pg, opts)
     pulled = [a for a, d in zip(trace["active_tiles"], trace["direction"]) if d == "pull"]
     assert res.pull_tiles_run == sum(pulled)
+    # the fold runs in every phase of every pull iteration, push ones skip it
+    folds = [r["value"] for r in telemetry.records() if r["name"] == "engine.fold_rows"]
+    assert folds == [len(pulled) * pg.split_rows]
     if direction == "push":
         assert res.pull_tiles_run == 0
     else:
@@ -234,9 +238,10 @@ def test_run_jit_carries_one_tile_count(rmat):
     assert scalars == [i32, b1, i32]
     assert [a.shape for a in carry if a.shape != ()] == [
         labels["label"].shape, (pg.p, pg.l, pg.sub_size // 32)]
-    # direction switch: the direction flag rides between changed and tiles
+    # direction switch: the direction flag rides between changed and tiles,
+    # and the pull iterations (the fold's count) follow the tiles
     carry, _ = _while_carry(bfs(3), g, pg, EngineOptions())
-    assert [a for a in carry if a.shape == ()] == [i32, b1, b1, i32]
+    assert [a for a in carry if a.shape == ()] == [i32, b1, b1, i32, i32]
     # static schedule: no counter in the carry
     carry, _ = _while_carry(pagerank(tol=1e-4), g, pg, EngineOptions())
     assert [a for a in carry if a.shape == ()] == [i32, b1]
